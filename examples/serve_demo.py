@@ -1,4 +1,4 @@
-"""Batched serving demo: continuous batching through prefill + decode.
+"""Batched serving demo: waves of one prompt length through prefill + decode.
 
 Run:  PYTHONPATH=src python examples/serve_demo.py [--arch qwen3-8b]
 (all archs run as tiny variants on CPU; --no-tiny for the full config)
@@ -42,7 +42,7 @@ def main() -> None:
     n_tok = sum(len(r.tokens) for r in results)
     print(f"{cfg.name}: {len(results)} requests -> {n_tok} tokens "
           f"in {dt:.1f}s ({n_tok / dt:.1f} tok/s, "
-          f"batch={args.batch_size} continuous)")
+          f"batch={args.batch_size})")
     for r in results[:5]:
         print(f"  req {r.request_id} (prompt {len(r.prompt)} toks): "
               f"{r.tokens[:10]}...")

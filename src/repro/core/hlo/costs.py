@@ -92,13 +92,12 @@ class HLOCostModel:
             out = sum(s.elements for s in op.shapes)
             return 2.0 * out * max(k, 1)
         if opc == "convolution":
+            lhs, rhs = (comp.op_by_name(name) for name in
+                        (tuple(op.operands) + (None, None))[:2])
             out = sum(s.elements for s in op.shapes)
-            m = re.search(r"window=\{size=([\dx]+)", op.attrs)
-            k = 1
-            if m:
-                for d in m.group(1).split("x"):
-                    k *= int(d)
-            return 2.0 * out * k
+            return 2.0 * out * op.conv_macs_per_output(
+                lhs.shapes[0] if lhs and lhs.shapes else None,
+                rhs.shapes[0] if rhs and rhs.shapes else None)
         if opc in ("fusion", "call"):
             total = 0.0
             for cname in op.called_computations:

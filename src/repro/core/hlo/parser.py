@@ -9,6 +9,7 @@ contraction dims).
 
 from __future__ import annotations
 
+import math
 import re
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence, Tuple
@@ -56,6 +57,23 @@ def parse_shapes(text: str) -> Tuple[Shape, ...]:
         dims = tuple(int(d) for d in m.group(2).split(",") if d != "")
         shapes.append(Shape(dtype=dtype, dims=dims))
     return tuple(shapes)
+
+
+def _valid_taps(n_out: int, n_in: int, window: int, stride: int, pad_lo: int,
+                lhs_dil: int, rhs_dil: int) -> int:
+    """(output, window tap) pairs of one spatial dim that read a real input
+    element rather than padding or a dilation hole."""
+    last = (n_in - 1) * lhs_dil  # last real position in the dilated input
+    period = lhs_dil // math.gcd(stride, lhs_dil)  # outputs between hits
+    count = 0
+    for k in range(window):
+        offset = k * rhs_dil - pad_lo  # position read by output 0
+        lo = max(0, -(offset // stride))  # first output reading >= 0
+        hi = min(n_out - 1, (last - offset) // stride)  # last reading <= last
+        for first in range(lo, min(lo + period, hi + 1)):
+            if (first * stride + offset) % lhs_dil == 0:
+                count += (hi - first) // period + 1
+    return count
 
 
 @dataclass
@@ -112,6 +130,45 @@ class HLOOp:
         if m:
             return len(m.group(1).split(","))
         return num_partitions
+
+    def conv_macs_per_output(self, lhs_shape: Optional[Shape],
+                             rhs_shape: Optional[Shape]) -> float:
+        """Multiply-adds behind each convolution output element, on average.
+
+        The TPU compiler writes every dot as a convolution and encodes batch
+        dims as spatial ones whose window mostly lands on padding or on the
+        holes of a dilation, so the count is the kernel's input features
+        times, per spatial dim, the window taps that land on real input."""
+        labels = re.search(r"dim_labels=(\w+)_(\w+)->(\w+)", self.attrs)
+        out_shape = self.shapes[0] if self.shapes else None
+        if not labels or lhs_shape is None or rhs_shape is None \
+                or out_shape is None:
+            return float(math.prod(self._window_attr("size")))
+        lhs_l, rhs_l, out_l = labels.groups()
+        macs = float(rhs_shape.dims[rhs_l.index("i")])
+        sizes = self._window_attr("size")
+        strides = self._window_attr("stride")
+        lhs_dil = self._window_attr("lhs_dilate")
+        rhs_dil = self._window_attr("rhs_dilate")
+        pads = re.search(r"pad=([\d_x-]+)", self.attrs)
+        pad_lo = [int(p.split("_")[0]) for p in pads.group(1).split("x")] \
+            if pads else []
+        for d, window in enumerate(sizes):
+            n_out = out_shape.dims[out_l.index(str(d))]
+            n_in = lhs_shape.dims[lhs_l.index(str(d))]
+            taps = _valid_taps(n_out, n_in, window,
+                               strides[d] if d < len(strides) else 1,
+                               pad_lo[d] if d < len(pad_lo) else 0,
+                               lhs_dil[d] if d < len(lhs_dil) else 1,
+                               rhs_dil[d] if d < len(rhs_dil) else 1)
+            macs *= taps / max(n_out, 1)
+        return macs
+
+    def _window_attr(self, key: str) -> Tuple[int, ...]:
+        m = re.search(r"window=\{[^}]*\b" + key + r"=([\dx]+)", self.attrs)
+        if not m:
+            return ()
+        return tuple(int(v) for v in m.group(1).split("x"))
 
     def dot_contracting(self, lhs_shape: Optional[Shape]) -> int:
         """Product of the LHS contracting dims of a dot (for FLOP counts)."""

@@ -173,8 +173,6 @@ def roofline_from_compiled(
     execution counts.
     """
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):  # newer jax: one dict per program
-        ca = ca[0] if ca else {}
     flops = float(ca.get("flops", 0.0))
     ca_bytes = float(ca.get("bytes accessed", 0.0))
     module = parse_hlo(hlo_text if hlo_text is not None else compiled.as_text())
@@ -192,13 +190,9 @@ def roofline_from_compiled(
     byts = cost_trips.module_bytes()
 
     stats = collective_stats(module, chip, exec_counts=cost_trips.execution_counts())
-    mem = None
-    try:
-        ma = compiled.memory_analysis()
-        mem = int(ma.argument_size_in_bytes + ma.output_size_in_bytes
-                  + ma.temp_size_in_bytes)
-    except Exception:
-        pass
+    ma = compiled.memory_analysis()
+    mem = int(ma.argument_size_in_bytes + ma.output_size_in_bytes
+              + ma.temp_size_in_bytes)
     report = RooflineReport(
         name=name,
         chip=chip,

@@ -1,9 +1,9 @@
 """Jit'd public wrappers for the Pallas kernels.
 
 These map model-layer layouts (B, S, H, D) onto the kernels' flattened
-layouts, broadcast GQA KV heads, and select ``interpret=True`` automatically
-off-TPU (CPU validation mode — the kernel body runs in Python, proving the
-tiling/masking logic against ``ref.py``).
+layouts and broadcast GQA KV heads.  They compile for the TPU; off the TPU a
+caller asks for ``interpret=True`` (CPU validation mode — the kernel body
+runs in Python, proving the tiling/masking logic against ``ref.py``).
 """
 
 from __future__ import annotations
@@ -19,17 +19,11 @@ from repro.kernels.rmsnorm import rmsnorm_rows
 from repro.kernels.ssd_scan import ssd_intra_chunk
 
 
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 @functools.partial(jax.jit, static_argnames=("causal", "window", "block_q",
                                              "block_k", "interpret"))
 def flash_attention(q, k, v, *, causal=True, window=0, block_q=128,
-                    block_k=128, interpret=None):
+                    block_k=128, interpret=False):
     """q: (B,S,H,D); k/v: (B,T,K,D) GQA -> (B,S,H,D)."""
-    if interpret is None:
-        interpret = _interpret_default()
     b, s, h, d = q.shape
     t, kh = k.shape[1], k.shape[2]
     g = h // kh
@@ -46,10 +40,8 @@ def flash_attention(q, k, v, *, causal=True, window=0, block_q=128,
 
 
 @functools.partial(jax.jit, static_argnames=("block_k", "interpret"))
-def flash_decode(q, k_cache, v_cache, lengths, *, block_k=512, interpret=None):
+def flash_decode(q, k_cache, v_cache, lengths, *, block_k=512, interpret=False):
     """q: (B,1,H,D); k/v cache: (B,T,K,D); lengths (B,) -> (B,1,H,D)."""
-    if interpret is None:
-        interpret = _interpret_default()
     b, _, h, d = q.shape
     t, kh = k_cache.shape[1], k_cache.shape[2]
     g = h // kh
@@ -63,18 +55,14 @@ def flash_decode(q, k_cache, v_cache, lengths, *, block_k=512, interpret=None):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def ssd_chunk_dual(xdt, cum, bm, cm, *, interpret=None):
+def ssd_chunk_dual(xdt, cum, bm, cm, *, interpret=False):
     """Kernel-backed intra-chunk SSD (see mamba2.ssd_chunked for the full op)."""
-    if interpret is None:
-        interpret = _interpret_default()
     return ssd_intra_chunk(xdt, cum, bm, cm, interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("eps", "interpret"))
-def fused_rmsnorm(x, w, *, eps=1e-5, interpret=None):
+def fused_rmsnorm(x, w, *, eps=1e-5, interpret=False):
     """x: (..., d) RMSNorm with learned scale."""
-    if interpret is None:
-        interpret = _interpret_default()
     shape = x.shape
     rows = 1
     for dim in shape[:-1]:

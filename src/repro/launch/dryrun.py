@@ -1,17 +1,19 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: ``.lower().compile()`` every (architecture × input
 shape × mesh) cell on placeholder devices and record memory / cost /
 roofline artifacts (task §MULTI-POD DRY-RUN).
 
-The two env lines above MUST precede every other import — jax locks the
-device count on first initialization.
+Run as a script, it asks the CPU backend for 512 placeholder devices before
+anything initializes a backend; importing the module changes nothing.
 
 Usage:
   PYTHONPATH=src python -m repro.launch.dryrun --arch tinyllama-1.1b --shape train_4k
   PYTHONPATH=src python -m repro.launch.dryrun --all [--multi-pod] [--out artifacts/]
 """
+
+import os
+
+if __name__ == "__main__":
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 
 import argparse  # noqa: E402
 import json  # noqa: E402

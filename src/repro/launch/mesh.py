@@ -12,13 +12,16 @@ import jax
 
 from repro.distributed import MeshContext
 
+# Every mesh axis is Auto: the model places activations with
+# ``with_sharding_constraint`` (``repro.distributed.constrain``), which only
+# Auto axes accept; ``jax.make_mesh`` would otherwise make them Explicit.
+AUTO = jax.sharding.AxisType.Auto
+
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    # axis_types/AxisType landed after jax 0.4.37; Auto is the default there
-    # and here, so omitting the kwarg is equivalent on every version.
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, (AUTO,) * len(axes))
 
 
 def make_mesh_context(*, multi_pod: bool = False) -> MeshContext:
@@ -46,9 +49,9 @@ def make_elastic_mesh_context(n_devices: Optional[int] = None,
                 break
     data = n // model_parallel
     if n <= len(jax.devices()):
-        mesh = jax.make_mesh((data, model_parallel), ("data", "model"))
+        mesh = jax.make_mesh((data, model_parallel), ("data", "model"),
+                             (AUTO, AUTO))
     else:
-        # jax 0.4.x AbstractMesh signature: one ((name, size), ...) tuple.
-        mesh = jax.sharding.AbstractMesh(
-            (("data", data), ("model", model_parallel)))
+        mesh = jax.sharding.AbstractMesh((data, model_parallel),
+                                         ("data", "model"), (AUTO, AUTO))
     return MeshContext(mesh=mesh, data_axes=("data",), model_axis="model")

@@ -180,9 +180,11 @@ def main() -> None:
 
     import jax
 
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.models import init_params
     from repro.serving import ServeEngine
 
+    enable_compile_cache()
     cfg = get_config(arch)
     if args.tiny:
         cfg = tiny_variant(cfg)

@@ -1,9 +1,11 @@
-"""Batched serving engine: continuous-batching prefill + decode loop.
+"""Batched serving engine: prefill + decode loop over waves of requests.
 
-Requests are padded into a fixed decode batch; finished slots are refilled
-from the queue (continuous batching).  Greedy sampling by default; the decode
-step is the jitted ``repro.models.decode_step`` — the same function the
-dry-run lowers for the ``decode_*`` cells.
+Requests are grouped into waves of up to ``batch_size`` prompts of one
+length: prefill applies no pad mask and decode keeps one position for every
+row, so a padded row would attend to its pads and its tokens would depend on
+its wave-mates.  Greedy sampling; the decode step is the jitted
+``repro.models.decode_step`` — the same function the dry-run lowers for the
+``decode_*`` cells.
 """
 
 from __future__ import annotations
@@ -24,6 +26,10 @@ class GenerationResult:
     request_id: int
     prompt: List[int]
     tokens: List[int]
+    # With ``generate(..., return_logits=True)``: float32 (len(tokens), V);
+    # row k holds the logits token k was chosen from (row 0 from prefill,
+    # row k from decode step k).
+    logits: Optional[np.ndarray] = None
 
 
 class ServeEngine:
@@ -35,7 +41,8 @@ class ServeEngine:
         self.params = params
         self.batch_size = batch_size
         self.max_len = max_len
-        self._decode = jax.jit(
+        # (params, cache, tokens (B, 1)) -> (logits (B, 1, V), cache)
+        self.decode = jax.jit(
             lambda p, c, t: decode_step(p, cfg, self.run, c, t))
         self._analysis = None
 
@@ -53,46 +60,65 @@ class ServeEngine:
         return self.analysis.analyze_batch(list(requests))
 
     def generate(self, prompts: List[List[int]], max_new_tokens: int = 16,
-                 eos_id: Optional[int] = None,
-                 frontend=None) -> List[GenerationResult]:
-        """Generate for a list of prompts with continuous batching."""
+                 eos_id: Optional[int] = None, frontend=None,
+                 return_logits: bool = False) -> List[GenerationResult]:
+        """Generate for a list of prompts, in waves of one prompt length.
+        ``frontend`` rows go to a wave's slots in order."""
+        by_len: Dict[int, list] = {}
+        for rid, p in enumerate(prompts):
+            by_len.setdefault(len(p), []).append((rid, p))
         results = []
-        queue = list(enumerate(prompts))
-        while queue:
-            wave = queue[:self.batch_size]
-            queue = queue[self.batch_size:]
-            results.extend(self._run_wave(wave, max_new_tokens, eos_id, frontend))
+        for group in by_len.values():
+            for i in range(0, len(group), self.batch_size):
+                results.extend(self._run_wave(
+                    group[i:i + self.batch_size], max_new_tokens, eos_id,
+                    frontend, return_logits))
         return sorted(results, key=lambda r: r.request_id)
 
-    def _run_wave(self, wave, max_new_tokens, eos_id, frontend):
-        b = len(wave)
-        plen = max(len(p) for _, p in wave)
-        tokens = np.zeros((b, plen), np.int32)
-        for i, (_, p) in enumerate(wave):
-            tokens[i, -len(p):] = p  # left-pad
-
+    def prefill_wave(self, prompts: List[List[int]], max_new_tokens: int,
+                     frontend=None):
+        """Prefill prompts of one length; returns the last position's logits
+        (B, 1, V) and a cache with room for ``max_new_tokens`` more tokens."""
+        lengths = sorted({len(p) for p in prompts})
+        if len(lengths) != 1:
+            raise ValueError(
+                f"a wave holds prompts of one length, got {lengths}")
+        if frontend is not None:
+            frontend = frontend[:len(prompts)]
         logits, cache = prefill(self.params, self.cfg, self.run,
-                                jnp.asarray(tokens), frontend=frontend)
-        # Grow the cache to the full generation budget.
-        cache = self._grow_cache(cache, plen + max_new_tokens, b)
+                                jnp.asarray(np.asarray(prompts, np.int32)),
+                                frontend=frontend)
+        cache = self._grow_cache(cache, lengths[0] + max_new_tokens,
+                                 len(prompts))
+        return logits, cache
 
+    def _run_wave(self, wave, max_new_tokens, eos_id, frontend, return_logits):
+        b = len(wave)
+        logits, cache = self.prefill_wave([p for _, p in wave], max_new_tokens,
+                                          frontend)
         out_tokens = [[] for _ in range(b)]
+        out_logits = [[] for _ in range(b)]
         done = [False] * b
-        cur = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
-        for _ in range(max_new_tokens):
+        for step in range(max_new_tokens):
+            cur = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
+            picked = np.asarray(cur)
+            rows = (np.asarray(logits[:, -1], np.float32)
+                    if return_logits else None)
             for i in range(b):
                 if not done[i]:
-                    tok = int(cur[i])
-                    out_tokens[i].append(tok)
-                    if eos_id is not None and tok == eos_id:
+                    out_tokens[i].append(int(picked[i]))
+                    if return_logits:
+                        out_logits[i].append(rows[i])
+                    if eos_id is not None and picked[i] == eos_id:
                         done[i] = True
-            if all(done):
+            if all(done) or step == max_new_tokens - 1:
                 break
-            logits, cache = self._decode(self.params, cache, cur[:, None])
-            cur = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
+            logits, cache = self.decode(self.params, cache, cur[:, None])
 
-        return [GenerationResult(request_id=rid, prompt=list(p),
-                                 tokens=out_tokens[i])
+        return [GenerationResult(
+                    request_id=rid, prompt=list(p), tokens=out_tokens[i],
+                    logits=(np.asarray(out_logits[i], np.float32)
+                            if return_logits else None))
                 for i, (rid, p) in enumerate(wave)]
 
     def _grow_cache(self, cache: Dict, new_len: int, batch: int) -> Dict:

@@ -74,9 +74,8 @@ DRYRUN_SMOKE = textwrap.dedent("""
     from repro.train import make_train_step
     from repro.train.state import abstract_train_state, state_shardings
 
-    # axis_types/AxisType landed after jax 0.4.37; Auto is the default
-    # everywhere, so passing nothing is equivalent on every version.
-    mesh = jax.make_mesh((4, 4), ("data", "model"))
+    auto = jax.sharding.AxisType.Auto
+    mesh = jax.make_mesh((4, 4), ("data", "model"), (auto, auto))
     ctx = MeshContext(mesh=mesh, data_axes=("data",), model_axis="model")
     set_mesh_context(ctx)
 
@@ -108,6 +107,7 @@ DRYRUN_SMOKE = textwrap.dedent("""
 def test_dryrun_smoke_16dev(arch):
     env = dict(os.environ)
     env["PYTHONPATH"] = "src"
+    env["JAX_PLATFORMS"] = "cpu"  # never reach for an accelerator
     env.pop("XLA_FLAGS", None)
     proc = subprocess.run(
         [sys.executable, "-c", DRYRUN_SMOKE.format(arch=arch)],

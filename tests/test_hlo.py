@@ -155,3 +155,40 @@ def test_tuple_type_with_index_comments():
     op = mod.entry.op_by_name("w")
     assert op is not None and op.opcode == "while"
     assert len(op.shapes) == 3
+
+
+def _conv_macs_brute(n, c, h, o, k, stride, pad, lhs_dil, rhs_dil):
+    """Multiply-adds of a 1-D NCH convolution, tap by tap."""
+    out = ((h - 1) * lhs_dil + 1 + pad[0] + pad[1]
+           - ((k - 1) * rhs_dil + 1)) // stride + 1
+    taps = sum(1 for oo in range(out) for kk in range(k)
+               if 0 <= oo * stride + kk * rhs_dil - pad[0] <= (h - 1) * lhs_dil
+               and (oo * stride + kk * rhs_dil - pad[0]) % lhs_dil == 0)
+    return n * o * c * taps
+
+
+@pytest.mark.parametrize("h,k,stride,pad,lhs_dil,rhs_dil", [
+    (13, 4, 1, (0, 0), 1, 1),
+    (13, 4, 2, (1, 2), 1, 1),
+    (13, 4, 1, (3, 3), 2, 1),
+    (13, 4, 3, (2, 0), 1, 2),
+    (13, 4, 2, (3, 3), 2, 1),
+    (13, 5, 3, (4, 1), 2, 2),
+    # A batch dim written as a spatial one, as the TPU compiler writes dots:
+    # a window of 32 over one real element padded by 31 on each side.
+    (1, 32, 1, (31, 31), 1, 1),
+])
+def test_convolution_flops_count_real_taps(h, k, stride, pad, lhs_dil,
+                                           rhs_dil):
+    x = jax.ShapeDtypeStruct((2, 8, h), jnp.float32)
+    w = jax.ShapeDtypeStruct((5, 8, k), jnp.float32)
+
+    def conv(a, b):
+        return jax.lax.conv_general_dilated(
+            a, b, (stride,), [pad], lhs_dilation=(lhs_dil,),
+            rhs_dilation=(rhs_dil,))
+
+    module = parse_hlo(jax.jit(conv).lower(x, w).compile().as_text())
+    flops = HLOCostModel(module, TPU_V5E).module_flops()
+    assert flops == 2 * _conv_macs_brute(2, 8, h, 5, k, stride, pad,
+                                         lhs_dil, rhs_dil)

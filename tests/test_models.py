@@ -83,6 +83,43 @@ def test_decode_matches_prefill_logits(arch_setup):
     assert int(cache2["pos"]) == expected_pos
 
 
+def test_serve_engine_mixed_prompt_lengths_match_forward():
+    """Interleaved prompt lengths: the logits behind every generated token
+    match the causal forward over the prompt plus the tokens before it, as
+    if the request had been served alone."""
+    from repro.models.transformer import forward_hidden, lm_logits
+    from repro.serving import ServeEngine
+
+    cfg = tiny_variant(get_config("tinyllama-1.1b"))
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    engine = ServeEngine(cfg, params, run=RUN, batch_size=2)
+    rng = np.random.default_rng(0)
+    prompts = [rng.integers(0, cfg.vocab, size=n).tolist()
+               for n in (5, 11, 5, 11, 8)]
+    results = engine.generate(prompts, max_new_tokens=4, return_logits=True)
+    assert [r.request_id for r in results] == list(range(len(prompts)))
+    for r in results:
+        seq = jnp.asarray([r.prompt + r.tokens])
+        hidden, _ = forward_hidden(params, cfg, RUN, seq)
+        ref = np.asarray(lm_logits(params, cfg, hidden), np.float32)[0]
+        assert r.logits.shape == (4, ref.shape[-1])
+        for k, got in enumerate(r.logits):
+            want = ref[len(r.prompt) + k - 1]
+            err = np.linalg.norm(got - want) / np.linalg.norm(want)
+            assert err < 0.05, (r.request_id, k, err)
+            assert r.tokens[k] == int(np.argmax(got))
+
+
+def test_serve_engine_prefill_wave_refuses_mixed_lengths():
+    from repro.serving import ServeEngine
+
+    cfg = tiny_variant(get_config("tinyllama-1.1b"))
+    engine = ServeEngine(cfg, init_params(cfg, jax.random.PRNGKey(0)),
+                         run=RUN, batch_size=2)
+    with pytest.raises(ValueError, match="one length"):
+        engine.prefill_wave([[1, 2, 3], [4, 5]], max_new_tokens=2)
+
+
 def test_attention_impls_agree():
     cfg = tiny_variant(get_config("qwen3-8b"))
     params = init_params(cfg, jax.random.PRNGKey(1))

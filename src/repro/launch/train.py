@@ -1,10 +1,11 @@
 """End-to-end training driver: ``python -m repro.launch.train --arch <id>``.
 
 Wires every substrate layer together: config registry, mesh, sharded train
-state, deterministic data pipeline, jitted train step, async checkpointing,
-heartbeat/straggler monitoring, and checkpoint/restart supervision.  It trains
-the tiny variants by default (a CPU-sized run); ``--no-tiny`` trains the
-published widths on whatever devices JAX finds.
+state, deterministic data pipeline, the train step compiled ahead of time
+(the program ``jit_train_step``), and async checkpointing with restore from
+the latest checkpoint.  It trains the tiny variants by default (a CPU-sized
+run); ``--no-tiny`` trains the published widths on whatever devices JAX
+finds.
 """
 
 from __future__ import annotations
@@ -21,7 +22,6 @@ from repro.configs.base import ShapeConfig
 from repro.data import DataPipeline
 from repro.distributed import MeshContext, set_mesh_context
 from repro.launch.compile_cache import enable_compile_cache
-from repro.launch.ft import HeartbeatRegistry, StragglerDetector
 from repro.launch.mesh import make_elastic_mesh_context
 from repro.launch.specs import batch_shardings, input_specs
 from repro.train import init_train_state, make_train_step
@@ -76,10 +76,6 @@ def train_loop(cfg, run: RunConfig, *, steps: int, global_batch: int,
 
         pipeline = DataPipeline(cfg, global_batch, seq_len, seed=seed,
                                 start_step=start_step, shardings=data_shardings)
-        hb = HeartbeatRegistry(timeout_s=120.0)
-        stragglers = StragglerDetector()
-        host = "host0"
-
         compiled = None
         metrics_out = []
         t_wall = time.time()
@@ -93,8 +89,6 @@ def train_loop(cfg, run: RunConfig, *, steps: int, global_batch: int,
             state, metrics = compiled(state, batch)
             jax.block_until_ready(metrics["loss"])
             dt = time.time() - t0
-            hb.beat(host)
-            stragglers.record(host, dt)
             if (step + 1) % log_every == 0 or step == start_step:
                 loss = float(metrics["loss"])
                 gnorm = float(metrics["grad_norm"])

@@ -79,7 +79,16 @@ def ssd_chunked(
     The per-head decay tensor (B,NC,Q,Q,H) is the memory hot-spot of the
     dual form; heads are processed in blocks of ``head_block`` (mirroring the
     Pallas kernel's per-head grid) so the peak is (B,NC,Q,Q,head_block).
+
+    Everything here runs under the named scope ``ssd``, which the compiled
+    program's ``op_name`` metadata carries (forward, backward and remat).
     """
+    with jax.named_scope("ssd"):
+        return _ssd_chunked(x, dt, A, Bm, Cm, chunk, h0, head_block,
+                            chunk_shard)
+
+
+def _ssd_chunked(x, dt, A, Bm, Cm, chunk, h0, head_block, chunk_shard):
     b, s, nh, p = x.shape
     n = Bm.shape[-1]
     q = min(chunk, s)
@@ -87,7 +96,7 @@ def ssd_chunked(
         # Right-pad to a chunk multiple: dt=0 there => decay 1, contribution
         # 0, so the final state equals the state after the s real steps.
         pad = q - s % q
-        y, h_last = ssd_chunked(
+        y, h_last = _ssd_chunked(
             jnp.pad(x, ((0, 0), (0, pad), (0, 0), (0, 0))),
             jnp.pad(dt, ((0, 0), (0, pad), (0, 0))),
             A,

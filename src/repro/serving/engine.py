@@ -3,9 +3,18 @@
 Requests are grouped into waves of up to ``batch_size`` prompts of one
 length: prefill applies no pad mask and decode keeps one position for every
 row, so a padded row would attend to its pads and its tokens would depend on
-its wave-mates.  Greedy sampling; the decode step is the jitted
-``repro.models.decode_step`` — the same function the dry-run lowers for the
-``decode_*`` cells.
+its wave-mates.  Greedy sampling; prefill is the jitted
+``repro.models.prefill`` (the program ``jit_prefill``) and the decode step
+the jitted ``repro.models.decode_step`` — the same function the dry-run
+lowers for the ``decode_*`` cells.
+
+Each wave is a host span ``serve.wave`` (``wave=<i>, rows=<b>``) of the
+profiler's trace (``jax.profiler.TraceAnnotation``: one clock with the
+device's operations, and next to no cost while no trace is taken), inside
+it ``serve.prefill`` (dispatch of the prefill), ``serve.grow_cache``,
+``serve.sample`` (argmax, its copy to the host and the per-row loop) and
+``serve.decode`` (dispatch of a decode step, ``step=<k>``), each carrying
+the wave's id.
 """
 
 from __future__ import annotations
@@ -16,6 +25,7 @@ from typing import Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs.base import ModelConfig, RunConfig
 from repro.models import decode_step, init_cache, prefill
@@ -41,10 +51,13 @@ class ServeEngine:
         self.params = params
         self.batch_size = batch_size
         self.max_len = max_len
+        # (params, cfg, run, tokens (B, S)) -> (logits (B, 1, V), cache)
+        self.prefill = jax.jit(prefill, static_argnums=(1, 2))
         # (params, cache, tokens (B, 1)) -> (logits (B, 1, V), cache)
         self.decode = jax.jit(
             lambda p, c, t: decode_step(p, cfg, self.run, c, t))
         self._analysis = None
+        self._waves = 0  # waves served so far: the next wave's id
 
     @property
     def analysis(self):
@@ -76,44 +89,52 @@ class ServeEngine:
         return sorted(results, key=lambda r: r.request_id)
 
     def prefill_wave(self, prompts: List[List[int]], max_new_tokens: int,
-                     frontend=None):
+                     frontend=None, wave: int = 0):
         """Prefill prompts of one length; returns the last position's logits
-        (B, 1, V) and a cache with room for ``max_new_tokens`` more tokens."""
+        (B, 1, V) and a cache with room for ``max_new_tokens`` more tokens.
+        ``wave``: the id its trace spans carry."""
         lengths = sorted({len(p) for p in prompts})
         if len(lengths) != 1:
             raise ValueError(
                 f"a wave holds prompts of one length, got {lengths}")
         if frontend is not None:
             frontend = frontend[:len(prompts)]
-        logits, cache = prefill(self.params, self.cfg, self.run,
-                                jnp.asarray(np.asarray(prompts, np.int32)),
-                                frontend=frontend)
-        cache = self._grow_cache(cache, lengths[0] + max_new_tokens,
-                                 len(prompts))
+        with TraceAnnotation("serve.prefill", wave=wave):
+            logits, cache = self.prefill(
+                self.params, self.cfg, self.run,
+                jnp.asarray(np.asarray(prompts, np.int32)), frontend=frontend)
+        with TraceAnnotation("serve.grow_cache", wave=wave):
+            cache = self._grow_cache(cache, lengths[0] + max_new_tokens,
+                                     len(prompts))
         return logits, cache
 
     def _run_wave(self, wave, max_new_tokens, eos_id, frontend, return_logits):
-        b = len(wave)
-        logits, cache = self.prefill_wave([p for _, p in wave], max_new_tokens,
-                                          frontend)
-        out_tokens = [[] for _ in range(b)]
-        out_logits = [[] for _ in range(b)]
-        done = [False] * b
-        for step in range(max_new_tokens):
-            cur = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
-            picked = np.asarray(cur)
-            rows = (np.asarray(logits[:, -1], np.float32)
-                    if return_logits else None)
-            for i in range(b):
-                if not done[i]:
-                    out_tokens[i].append(int(picked[i]))
-                    if return_logits:
-                        out_logits[i].append(rows[i])
-                    if eos_id is not None and picked[i] == eos_id:
-                        done[i] = True
-            if all(done) or step == max_new_tokens - 1:
-                break
-            logits, cache = self.decode(self.params, cache, cur[:, None])
+        b, wid = len(wave), self._waves
+        self._waves += 1
+        with TraceAnnotation("serve.wave", wave=wid, rows=b):
+            logits, cache = self.prefill_wave(
+                [p for _, p in wave], max_new_tokens, frontend, wave=wid)
+            out_tokens = [[] for _ in range(b)]
+            out_logits = [[] for _ in range(b)]
+            done = [False] * b
+            for step in range(max_new_tokens):
+                with TraceAnnotation("serve.sample", wave=wid):
+                    cur = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
+                    picked = np.asarray(cur)
+                    rows = (np.asarray(logits[:, -1], np.float32)
+                            if return_logits else None)
+                    for i in range(b):
+                        if not done[i]:
+                            out_tokens[i].append(int(picked[i]))
+                            if return_logits:
+                                out_logits[i].append(rows[i])
+                            if eos_id is not None and picked[i] == eos_id:
+                                done[i] = True
+                if all(done) or step == max_new_tokens - 1:
+                    break
+                with TraceAnnotation("serve.decode", wave=wid, step=step):
+                    logits, cache = self.decode(self.params, cache,
+                                                cur[:, None])
 
         return [GenerationResult(
                     request_id=rid, prompt=list(p), tokens=out_tokens[i],

@@ -2,6 +2,8 @@
 
 ``make_train_step`` closes over the configs so the jitted signature is
 ``(state, batch) -> (state, metrics)`` — the function the dry-run lowers.
+The head projection and the loss run under the named scope ``head_loss``,
+which the compiled program's ``op_name`` metadata carries.
 """
 
 from __future__ import annotations
@@ -22,12 +24,13 @@ from repro.train.state import TrainState
 def _loss_fn(params, cfg: ModelConfig, run: RunConfig, batch):
     hidden, extras = forward_train(params, cfg, run, batch["tokens"],
                                    frontend=batch.get("frontend"))
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
     labels = batch["labels"]
     if hidden.shape[1] != labels.shape[1]:  # vlm: frontend positions unsupervised
         hidden = hidden[:, hidden.shape[1] - labels.shape[1]:]
-    loss, acc = cross_entropy_loss(hidden, head, labels, chunk=run.loss_chunk,
-                                   vocab=cfg.vocab)
+    with jax.named_scope("head_loss"):
+        head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+        loss, acc = cross_entropy_loss(hidden, head, labels,
+                                       chunk=run.loss_chunk, vocab=cfg.vocab)
     aux = extras.get("aux", jnp.zeros((), jnp.float32))
     total = loss + 0.01 * aux
     return total, {"loss": loss, "aux": aux, "accuracy": acc}
@@ -99,4 +102,6 @@ def eval_step(state: TrainState, batch, cfg: ModelConfig, run: RunConfig):
 
 
 def make_train_step(cfg: ModelConfig, run: RunConfig):
-    return functools.partial(train_step, cfg=cfg, run=run)
+    step = functools.partial(train_step, cfg=cfg, run=run)
+    step.__name__ = "train_step"  # its jit's program is ``jit_train_step``
+    return step

@@ -1,8 +1,10 @@
 from repro.kernels.ops import (
     flash_attention,
     flash_decode,
+    flash_decode_stacked,
     fused_rmsnorm,
     ssd_chunk_dual,
 )
 
-__all__ = ["flash_attention", "flash_decode", "fused_rmsnorm", "ssd_chunk_dual"]
+__all__ = ["flash_attention", "flash_decode", "flash_decode_stacked",
+           "fused_rmsnorm", "ssd_chunk_dual"]

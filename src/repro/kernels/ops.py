@@ -13,7 +13,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.decode_attention import decode_attention_bkgd
+from repro.kernels.decode_attention import decode_attention_stacked
 from repro.kernels.flash_attention import flash_attention_bhsd
 from repro.kernels.rmsnorm import rmsnorm_rows
 from repro.kernels.ssd_scan import ssd_intra_chunk
@@ -40,18 +40,22 @@ def flash_attention(q, k, v, *, causal=True, window=0, block_q=128,
 
 
 @functools.partial(jax.jit, static_argnames=("block_k", "interpret"))
+def flash_decode_stacked(q, k_stack, v_stack, layer, lengths, *, block_k=None,
+                         interpret=False):
+    """q: (B,1,H,D); k/v stack: (L,B,T,K,D), read at ``layer`` in place;
+    lengths (B,) -> (B,1,H,D)."""
+    out = decode_attention_stacked(q[:, 0], k_stack, v_stack, layer, lengths,
+                                   block_k=block_k, interpret=interpret)
+    return out[:, None]
+
+
+@functools.partial(jax.jit, static_argnames=("block_k", "interpret"))
 def flash_decode(q, k_cache, v_cache, lengths, *, block_k=512, interpret=False):
-    """q: (B,1,H,D); k/v cache: (B,T,K,D); lengths (B,) -> (B,1,H,D)."""
-    b, _, h, d = q.shape
-    t, kh = k_cache.shape[1], k_cache.shape[2]
-    g = h // kh
-    qf = q[:, 0].reshape(b, kh, g, d).reshape(b * kh, g, d)
-    kf = k_cache.transpose(0, 2, 1, 3).reshape(b * kh, t, d)
-    vf = v_cache.transpose(0, 2, 1, 3).reshape(b * kh, t, d)
-    lens = jnp.repeat(lengths.astype(jnp.int32), kh)
-    of = decode_attention_bkgd(qf, kf, vf, lens,
-                               block_k=min(block_k, t), interpret=interpret)
-    return of.reshape(b, kh * g, d)[:, None]
+    """q: (B,1,H,D); k/v cache: (B,T,K,D); lengths (B,) -> (B,1,H,D): the
+    one-layer case of ``flash_decode_stacked``."""
+    return flash_decode_stacked(
+        q, k_cache[None], v_cache[None], jnp.zeros((), jnp.int32), lengths,
+        block_k=min(block_k, k_cache.shape[1]), interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))

@@ -11,7 +11,9 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.distributed import constrain
+from repro.distributed import constrain, current_mesh
+from repro.kernels.decode_attention import max_block_k
+from repro.kernels.ops import flash_decode_stacked
 
 DATA = ("pod", "data")  # batch axes (sanitized away when mesh lacks "pod")
 MODEL = "model"
@@ -192,6 +194,37 @@ def decode_attention(
     return out.reshape(b, 1, h, d).astype(q.dtype)
 
 
+def stacked_decode_attention(
+    q: jnp.ndarray, k_stack: jnp.ndarray, v_stack: jnp.ndarray,
+    layer: jnp.ndarray, lengths: jnp.ndarray, window: int = 0,
+    softcap: float = 0.0,
+) -> jnp.ndarray:
+    """``decode_attention`` against layer ``layer`` of a stacked (L,B,T,K,D)
+    cache.
+
+    On the TPU the flash-decode kernel reads the layer's valid blocks where
+    the stack holds them.  Off the TPU, and where the kernel lacks a feature
+    (a window over a linear cache, a logit softcap), where not even its
+    smallest block of every KV head fits its VMEM budget, or where the cache
+    is sharded over a mesh, the XLA read takes the layer's slice.
+    """
+    def xla(q, k_stack, v_stack, layer, lengths):
+        return decode_attention(
+            q, jax.lax.dynamic_index_in_dim(k_stack, layer, keepdims=False),
+            jax.lax.dynamic_index_in_dim(v_stack, layer, keepdims=False),
+            lengths, window=window, softcap=softcap)
+
+    mesh = current_mesh()
+    _, _, h, d = q.shape
+    fits = max_block_k(k_stack.shape[3], d, h, k_stack.dtype.itemsize) > 0
+    if (window > 0 or softcap > 0 or not fits
+            or (mesh is not None and mesh.mesh.size > 1)):
+        return xla(q, k_stack, v_stack, layer, lengths)
+    return jax.lax.platform_dependent(
+        q, k_stack, v_stack, layer, lengths, tpu=flash_decode_stacked,
+        default=xla)
+
+
 # ---------------------------------------------------------------------------
 # Attention block (projection + rope + qk-norm wrapper)
 # ---------------------------------------------------------------------------
@@ -206,6 +239,7 @@ def attention_block(
     kv_cache: Optional[Tuple[jnp.ndarray, jnp.ndarray]] = None,
     cache_pos: Optional[jnp.ndarray] = None,
     cache_fill: Optional[jnp.ndarray] = None,
+    cache_layer: Optional[jnp.ndarray] = None,
     causal: bool = True,
     kv_x: Optional[jnp.ndarray] = None,
     use_rope: bool = True,
@@ -214,9 +248,11 @@ def attention_block(
 
     * prefill/train: ``new_kv`` is this segment's rope'd (K, V) — the caller
       may install it as the cache.
-    * decode (``kv_cache`` + scalar ``cache_pos`` given): the new token's K/V
-      is written into the cache at ``cache_pos`` and ``new_kv`` is the
-      updated cache.
+    * decode (``kv_cache`` + scalar ``cache_pos`` and ``cache_layer`` given):
+      ``kv_cache`` is the stacked (L,B,T,K,D) cache of every layer; the new
+      token's K/V is written in place at (``cache_layer``, ``cache_pos``),
+      attention reads that layer from the stack, and ``new_kv`` is the
+      updated stack.
     * ``kv_x`` selects cross-attention (encoder output as KV source, no rope).
     """
     b, s, _ = x.shape
@@ -239,18 +275,20 @@ def attention_block(
 
     if kv_cache is not None:
         k_cache, v_cache = kv_cache
-        k_cache = jax.lax.dynamic_update_slice_in_dim(
-            k_cache, kk.astype(k_cache.dtype), cache_pos, axis=1)
-        v_cache = jax.lax.dynamic_update_slice_in_dim(
-            v_cache, vv.astype(v_cache.dtype), cache_pos, axis=1)
+        at = (cache_layer, 0, cache_pos, 0, 0)
+        k_cache = jax.lax.dynamic_update_slice(
+            k_cache, kk[None].astype(k_cache.dtype), at)
+        v_cache = jax.lax.dynamic_update_slice(
+            v_cache, vv[None].astype(v_cache.dtype), at)
         fill = cache_fill if cache_fill is not None else cache_pos + s
         lengths = jnp.full((b,), fill, dtype=jnp.int32)
         # Ring-buffer caches (windowed attention) index positions modulo the
         # buffer, so the window re-mask inside decode_attention must be off
         # (every live slot is in-window by construction).
         win = 0 if cache_fill is not None else cfg.window
-        out = decode_attention(q, k_cache, v_cache, lengths,
-                               window=win, softcap=cfg.attn_logit_softcap)
+        out = stacked_decode_attention(q, k_cache, v_cache, cache_layer,
+                                       lengths, window=win,
+                                       softcap=cfg.attn_logit_softcap)
         new_kv = (k_cache, v_cache)
     else:
         if run.attention_impl == "naive":

@@ -162,11 +162,12 @@ def _seq_constrain(x, run: RunConfig):
 
 
 def dense_block(lp, x, cfg, run, positions, causal=True, use_rope=True,
-                kv_cache=None, cache_pos=None, enc_out=None):
+                kv_cache=None, cache_pos=None, cache_layer=None, enc_out=None):
     """One pre-norm transformer block (+ optional cross-attention)."""
     h, kv = attention_block(lp["attn"], rms_norm(x, lp["norm1"], cfg.norm_eps),
                             cfg, run, positions, kv_cache=kv_cache,
-                            cache_pos=cache_pos, causal=causal, use_rope=use_rope)
+                            cache_pos=cache_pos, cache_layer=cache_layer,
+                            causal=causal, use_rope=use_rope)
     x = _seq_constrain(x + h, run)
     if enc_out is not None:
         cross = lp["cross"]
@@ -180,10 +181,11 @@ def dense_block(lp, x, cfg, run, positions, causal=True, use_rope=True,
     return _seq_constrain(x + h, run), kv
 
 
-def moe_layer_block(lp, x, cfg, run, positions, kv_cache=None, cache_pos=None):
+def moe_layer_block(lp, x, cfg, run, positions, kv_cache=None, cache_pos=None,
+                    cache_layer=None):
     h, kv = attention_block(lp["attn"], rms_norm(x, lp["norm1"], cfg.norm_eps),
                             cfg, run, positions, kv_cache=kv_cache,
-                            cache_pos=cache_pos)
+                            cache_pos=cache_pos, cache_layer=cache_layer)
     x = _seq_constrain(x + h, run)
     h, aux = moe_block(lp["moe"], rms_norm(x, lp["norm2"], cfg.norm_eps), cfg,
                        dispatch_mode=run.moe_dispatch)
@@ -191,13 +193,15 @@ def moe_layer_block(lp, x, cfg, run, positions, kv_cache=None, cache_pos=None):
 
 
 def hybrid_shared_block(params, x, x0, inv_proj, cfg, run, positions,
-                        kv_cache=None, cache_pos=None, cache_fill=None):
+                        kv_cache=None, cache_pos=None, cache_fill=None,
+                        cache_layer=None):
     """Zamba2 shared attention block on concat(x, embed0)."""
     xin = jnp.concatenate([x, x0], axis=-1)
     h, kv = attention_block(params["shared_attn"],
                             rms_norm(xin, params["shared_norm1"], cfg.norm_eps),
                             cfg, run, positions, kv_cache=kv_cache,
-                            cache_pos=cache_pos, cache_fill=cache_fill)
+                            cache_pos=cache_pos, cache_fill=cache_fill,
+                            cache_layer=cache_layer)
     m = mlp_block(params["shared_mlp"],
                   rms_norm(xin, params["shared_norm2"], cfg.norm_eps), cfg.act)
     return _seq_constrain(x + (h + m) @ inv_proj, run), kv
@@ -448,6 +452,21 @@ def prefill(params, cfg, run, tokens, frontend=None):
     return logits_last, cache
 
 
+def _scan_with_cache(body, x, xs, k, v):
+    """Scan ``body(x, xs_l, layer, k, v) -> (x, (k, v), y)`` over the layer
+    stack, carrying the stacked caches and the layer index: each layer
+    writes and reads its KV in place, and no layer's cache is sliced out or
+    stacked back.  Returns (x, k, v, stacked ys)."""
+    def step(carry, xs_l):
+        x, layer, k, v = carry
+        x, (k, v), y = body(x, xs_l, layer, k, v)
+        return (x, layer + 1, k, v), y
+
+    (x, _, k, v), ys = jax.lax.scan(
+        step, (x, jnp.zeros((), jnp.int32), k, v), xs)
+    return x, k, v, ys
+
+
 def decode_step(params, cfg, run, cache, tokens):
     """One decode step: tokens (B,1) + cache -> (logits (B,1,V), new cache).
 
@@ -467,29 +486,27 @@ def decode_step(params, cfg, run, cache, tokens):
                 sinusoidal_positions(cache["k"].shape[2], cfg.d_model),
                 pos, 1, axis=0).astype(x.dtype)[None]
 
-        if fam == "moe" and cfg.moe_first_dense:
-            def dbody(carry, inputs):
-                lp, kl, vl = inputs
-                new, (kl2, vl2) = dense_block(lp, carry, cfg, run, positions,
-                                              kv_cache=(kl, vl), cache_pos=pos)
-                return new, (kl2, vl2)
-            x, (dk, dv) = jax.lax.scan(
-                dbody, x, (params["dense_layers"], cache["dk"], cache["dv"]))
-            new_cache["dk"], new_cache["dv"] = dk, dv
+        def dense_body(carry, lp, layer, k, v):
+            return *dense_block(lp, carry, cfg, run, positions, kv_cache=(k, v),
+                                cache_pos=pos, cache_layer=layer), None
 
-        def body(carry, inputs):
+        if fam == "moe" and cfg.moe_first_dense:
+            x, new_cache["dk"], new_cache["dv"], _ = _scan_with_cache(
+                dense_body, x, params["dense_layers"], cache["dk"],
+                cache["dv"])
+
+        def body(carry, inputs, layer, k, v):
             if fam == "moe":
-                lp, kl, vl = inputs
-                new, (kl2, vl2), _aux = moe_layer_block(
-                    lp, carry, cfg, run, positions, kv_cache=(kl, vl),
-                    cache_pos=pos)
-                return new, (kl2, vl2)
+                new, kv, _aux = moe_layer_block(
+                    inputs, carry, cfg, run, positions, kv_cache=(k, v),
+                    cache_pos=pos, cache_layer=layer)
+                return new, kv, None
             if fam == "audio":
-                lp, kl, vl, ckl, cvl = inputs
-                h, (kl2, vl2) = attention_block(
+                lp, ckl, cvl = inputs
+                h, kv = attention_block(
                     lp["attn"], rms_norm(carry, lp["norm1"], cfg.norm_eps),
-                    cfg, run, positions, kv_cache=(kl, vl), cache_pos=pos,
-                    use_rope=False)
+                    cfg, run, positions, kv_cache=(k, v), cache_pos=pos,
+                    cache_layer=layer, use_rope=False)
                 xx = carry + h
                 cp = {"wq": lp["cross"]["cross_wq"], "wk": lp["cross"]["cross_wk"],
                       "wv": lp["cross"]["cross_wv"], "wo": lp["cross"]["cross_wo"]}
@@ -501,19 +518,15 @@ def decode_step(params, cfg, run, cache, tokens):
                 xx = xx + att.reshape(b, 1, -1) @ cp["wo"]
                 h2 = mlp_block(lp["mlp"], rms_norm(xx, lp["norm2"], cfg.norm_eps),
                                cfg.act)
-                return xx + h2, (kl2, vl2)
-            lp, kl, vl = inputs
-            new, (kl2, vl2) = dense_block(lp, carry, cfg, run, positions,
-                                          kv_cache=(kl, vl), cache_pos=pos)
-            return new, (kl2, vl2)
+                return xx + h2, kv, None
+            return dense_body(carry, inputs, layer, k, v)
 
         if fam == "audio":
-            xs = (params["layers"], cache["k"], cache["v"],
-                  cache["cross_k"], cache["cross_v"])
+            xs = (params["layers"], cache["cross_k"], cache["cross_v"])
         else:
-            xs = (params["layers"], cache["k"], cache["v"])
-        x, (k, v) = jax.lax.scan(body, x, xs)
-        new_cache["k"], new_cache["v"] = k, v
+            xs = params["layers"]
+        x, new_cache["k"], new_cache["v"], _ = _scan_with_cache(
+            body, x, xs, cache["k"], cache["v"])
 
     elif fam == "ssm":
         def body(carry, inputs):
@@ -531,9 +544,8 @@ def decode_step(params, cfg, run, cache, tokens):
         wlen = cache["k"].shape[2]
         slot = jnp.mod(pos, wlen)
 
-        def group_body(carry, inputs):
-            xg = carry
-            lp, ssm_g, conv_g, kl, vl = inputs
+        def group_body(xg, inputs, group, k, v):
+            lp, ssm_g, conv_g = inputs
 
             def inner(c, xs_inner):
                 lpi, ssm, conv = xs_inner
@@ -543,20 +555,21 @@ def decode_step(params, cfg, run, cache, tokens):
                                              single_step=True)
                 return c + y, (ssm2, conv2)
 
-            xg, (ssm2, conv2) = jax.lax.scan(
+            xg, states = jax.lax.scan(
                 inner, xg,
                 ({"mamba": lp["mamba"], "norm1": lp["norm1"]}, ssm_g, conv_g))
-            xg, (kl2, vl2) = hybrid_shared_block(
+            xg, kv = hybrid_shared_block(
                 params, xg, x0, lp["inv_proj"], cfg, run, positions,
-                kv_cache=(kl, vl), cache_pos=slot,
-                cache_fill=jnp.minimum(pos + 1, wlen))
-            return xg, (ssm2, conv2, kl2, vl2)
+                kv_cache=(k, v), cache_pos=slot,
+                cache_fill=jnp.minimum(pos + 1, wlen), cache_layer=group)
+            return xg, kv, states
 
         stacked = ({"mamba": params["layers"]["mamba"],
                     "norm1": params["layers"]["norm1"],
                     "inv_proj": params["inv_proj"]},
-                   cache["ssm"], cache["conv"], cache["k"], cache["v"])
-        x, (ssm, conv, k, v) = jax.lax.scan(group_body, x, stacked)
+                   cache["ssm"], cache["conv"])
+        x, k, v, (ssm, conv) = _scan_with_cache(
+            group_body, x, stacked, cache["k"], cache["v"])
         new_cache.update({"ssm": ssm, "conv": conv, "k": k, "v": v})
 
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)

@@ -6,7 +6,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels import flash_attention, flash_decode, fused_rmsnorm, ssd_chunk_dual
+from repro.kernels import (flash_attention, flash_decode, flash_decode_stacked,
+                           fused_rmsnorm, ssd_chunk_dual)
 from repro.kernels import ref
 
 
@@ -79,6 +80,86 @@ def test_flash_decode_sweep(dtype, t, h, kh, d, bk):
     exp = exp.reshape(b, h, d)[:, None]
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(exp, np.float32), **_tol(dtype))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("block_k", [256, 640])
+@pytest.mark.parametrize("fill", ["1", "block_k", "block_k+1", "T"])
+@pytest.mark.parametrize("n_layers,layer", [(1, 0), (3, 0), (3, 1), (3, 2)])
+def test_flash_decode_stacked_sweep(n_layers, layer, fill, block_k, dtype):
+    """One layer of a stacked (L,B,T,K,D) cache read in place: T = 1280,
+    GQA with 4 KV heads of 8 query heads each; row 0 holds ``fill`` valid
+    positions, row 1 all of them."""
+    t, kh, g, d, b = 1280, 4, 8, 128, 2
+    fill = {"1": 1, "block_k": block_k, "block_k+1": block_k + 1,
+            "T": t}[fill]
+    keys = jax.random.split(jax.random.PRNGKey(7), 3)
+    q = jax.random.normal(keys[0], (b, 1, kh * g, d), dtype)
+    ks = jax.random.normal(keys[1], (n_layers, b, t, kh, d), dtype)
+    vs = jax.random.normal(keys[2], (n_layers, b, t, kh, d), dtype)
+    lengths = jnp.array([fill, t], jnp.int32)
+    out = flash_decode_stacked(q, ks, vs, jnp.int32(layer), lengths,
+                               block_k=block_k, interpret=True)
+    exp = _stacked_expected(q, ks, vs, layer, lengths)
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(exp, np.float32), **_tol(dtype))
+
+
+def _stacked_expected(q, ks, vs, layer, lengths):
+    b, _, h, d = q.shape
+    t, kh = ks.shape[2], ks.shape[3]
+    qf = q[:, 0].reshape(b * kh, h // kh, d)
+    kf = ks[layer].transpose(0, 2, 1, 3).reshape(b * kh, t, d)
+    vf = vs[layer].transpose(0, 2, 1, 3).reshape(b * kh, t, d)
+    exp = ref.decode_attention_ref(qf, kf, vf, jnp.repeat(lengths, kh))
+    return exp.reshape(b, h, d)[:, None]
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("fill", ["1", "block_k", "block_k+1", "T"])
+@pytest.mark.parametrize("t,kh,g,d", [
+    (1027, 4, 8, 128),  # yi-9b heads, a cache length no block divides
+    (1027, 4, 12, 128),  # starcoder2-15b: G = 12
+    (600, 32, 1, 80),  # zamba2-2.7b: 32 KV heads of 80
+])
+def test_flash_decode_stacked_default_blocks(t, kh, g, d, fill, dtype):
+    """The default block size at published head widths, where the cache
+    length leaves a partial last block: positions past T are masked."""
+    from repro.kernels.decode_attention import default_block_k
+    b, n_layers, layer = 2, 3, 2
+    bk = default_block_k(t, kh, d, kh * g, jnp.dtype(dtype).itemsize)
+    assert t % bk and bk < t
+    fill = {"1": 1, "block_k": bk, "block_k+1": bk + 1, "T": t}[fill]
+    keys = jax.random.split(jax.random.PRNGKey(11), 3)
+    q = jax.random.normal(keys[0], (b, 1, kh * g, d), dtype)
+    ks = jax.random.normal(keys[1], (n_layers, b, t, kh, d), dtype)
+    vs = jax.random.normal(keys[2], (n_layers, b, t, kh, d), dtype)
+    lengths = jnp.array([fill, t], jnp.int32)
+    out = flash_decode_stacked(q, ks, vs, jnp.int32(layer), lengths,
+                               interpret=True)
+    exp = _stacked_expected(q, ks, vs, layer, lengths)
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(exp, np.float32), **_tol(dtype))
+
+
+@pytest.mark.parametrize("t,kh,g,d,itemsize,expected", [
+    (1280, 4, 8, 128, 2, 640),  # the serve cell: two equal blocks
+    (1027, 4, 8, 128, 2, 528),  # two blocks, the last partial
+    (512, 4, 8, 64, 2, 512),  # the whole cache in one block
+    (4096, 32, 1, 80, 2, 256),  # zamba2-2.7b's ring: 32 KV heads
+    (2048, 16, 1, 128, 2, 512),  # deepseek-moe-16b
+    (1280, 32, 1, 128, 4, 128),  # float32, 32 KV heads: the smallest block
+])
+def test_decode_block_k_follows_vmem_budget(t, kh, g, d, itemsize, expected):
+    from repro.kernels.decode_attention import (MIN_BLOCK, VMEM_BUDGET,
+                                                _bytes_per_position,
+                                                default_block_k, max_block_k)
+    bk = default_block_k(t, kh, d, kh * g, itemsize)
+    assert bk == expected
+    assert bk * _bytes_per_position(kh, d, kh * g, itemsize) <= VMEM_BUDGET
+    assert max_block_k(kh, d, kh * g, itemsize) % MIN_BLOCK == 0
+    # Widths at which not even MIN_BLOCK positions fit take the XLA read.
+    assert max_block_k(64, 256, 64, 4) == 0
 
 
 @pytest.mark.parametrize("q,p,n,h", [(32, 32, 16, 2), (64, 64, 32, 3),

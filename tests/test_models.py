@@ -1,6 +1,8 @@
 """Per-architecture smoke tests (reduced configs, CPU): one forward/train
 step with shape + finiteness assertions, plus prefill/decode consistency."""
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -81,6 +83,143 @@ def test_decode_matches_prefill_logits(arch_setup):
     expected_pos = tokens.shape[1] + (
         cfg.frontend_len if cfg.frontend == "vision_stub" else 0)
     assert int(cache2["pos"]) == expected_pos
+
+
+def _xs_decode_step(params, cfg, run, cache, tokens):
+    """Reference: ``decode_step`` as it was before the stacked caches were
+    carried through the layer scan.  Each layer's K/V slice goes in as a
+    scan input and the updated slice comes out as a scan output, and the
+    block sees it as a one-layer stack."""
+    from repro.models.layers import (attention_block, decode_attention,
+                                     mlp_block, rms_norm, sinusoidal_positions)
+    from repro.models.mamba2 import mamba_block
+    from repro.models.transformer import (dense_block, embed_tokens,
+                                          hybrid_shared_block, lm_logits,
+                                          moe_layer_block)
+
+    def one(kl, vl):
+        return kl[None], vl[None]
+
+    def unstack(kv):
+        return kv[0][0], kv[1][0]
+
+    fam, pos, b = cfg.family, cache["pos"], tokens.shape[0]
+    x = embed_tokens(params, cfg, tokens)
+    positions = jnp.broadcast_to(pos[None, None], (b, 1))
+    new = dict(cache)
+    at = dict(cache_pos=pos, cache_layer=0)
+    if fam in ("dense", "moe", "audio"):
+        if fam == "audio":
+            x = x + jax.lax.dynamic_slice_in_dim(
+                sinusoidal_positions(cache["k"].shape[2], cfg.d_model),
+                pos, 1, axis=0).astype(x.dtype)[None]
+
+        def dense_body(carry, inputs):
+            lp, kl, vl = inputs
+            carry, kv = dense_block(lp, carry, cfg, run, positions,
+                                    kv_cache=one(kl, vl), **at)
+            return carry, unstack(kv)
+
+        if fam == "moe" and cfg.moe_first_dense:
+            x, (new["dk"], new["dv"]) = jax.lax.scan(
+                dense_body, x,
+                (params["dense_layers"], cache["dk"], cache["dv"]))
+
+        def body(carry, inputs):
+            if fam == "moe":
+                lp, kl, vl = inputs
+                carry, kv, _ = moe_layer_block(lp, carry, cfg, run, positions,
+                                               kv_cache=one(kl, vl), **at)
+                return carry, unstack(kv)
+            if fam == "audio":
+                lp, kl, vl, ckl, cvl = inputs
+                h, kv = attention_block(
+                    lp["attn"], rms_norm(carry, lp["norm1"], cfg.norm_eps),
+                    cfg, run, positions, kv_cache=one(kl, vl),
+                    use_rope=False, **at)
+                xx = carry + h
+                cross = lp["cross"]
+                q = (rms_norm(xx, lp["norm3"], cfg.norm_eps)
+                     @ cross["cross_wq"]).reshape(b, 1, cfg.n_heads, cfg.d_head)
+                att = decode_attention(q, ckl, cvl,
+                                       jnp.full((b,), ckl.shape[1], jnp.int32))
+                xx = xx + att.reshape(b, 1, -1) @ cross["cross_wo"]
+                h2 = mlp_block(lp["mlp"], rms_norm(xx, lp["norm2"],
+                                                   cfg.norm_eps), cfg.act)
+                return xx + h2, unstack(kv)
+            return dense_body(carry, inputs)
+
+        xs = (params["layers"], cache["k"], cache["v"])
+        if fam == "audio":
+            xs += (cache["cross_k"], cache["cross_v"])
+        x, (new["k"], new["v"]) = jax.lax.scan(body, x, xs)
+    else:
+        assert fam == "hybrid", fam
+        x0, wlen = x, cache["k"].shape[2]
+
+        def group_body(xg, inputs):
+            lp, ssm_g, conv_g, kl, vl = inputs
+
+            def inner(c, xs_inner):
+                lpi, ssm, conv = xs_inner
+                y, ssm2, conv2 = mamba_block(
+                    lpi["mamba"], rms_norm(c, lpi["norm1"], cfg.norm_eps), cfg,
+                    ssm_state=ssm, conv_state=conv, single_step=True)
+                return c + y, (ssm2, conv2)
+
+            xg, states = jax.lax.scan(
+                inner, xg,
+                ({"mamba": lp["mamba"], "norm1": lp["norm1"]}, ssm_g, conv_g))
+            xg, kv = hybrid_shared_block(
+                params, xg, x0, lp["inv_proj"], cfg, run, positions,
+                kv_cache=one(kl, vl), cache_pos=jnp.mod(pos, wlen),
+                cache_fill=jnp.minimum(pos + 1, wlen), cache_layer=0)
+            return xg, (states, unstack(kv))
+
+        stacked = ({"mamba": params["layers"]["mamba"],
+                    "norm1": params["layers"]["norm1"],
+                    "inv_proj": params["inv_proj"]},
+                   cache["ssm"], cache["conv"], cache["k"], cache["v"])
+        x, ((new["ssm"], new["conv"]), (new["k"], new["v"])) = jax.lax.scan(
+            group_body, x, stacked)
+    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+    new["pos"] = pos + 1
+    return lm_logits(params, cfg, x), new
+
+
+@pytest.mark.parametrize("arch,changes", [
+    ("tinyllama-1.1b", {}),
+    ("deepseek-moe-16b", {"n_layers": 3}),  # 1 dense + 2 MoE layers
+    ("zamba2-2.7b", {"n_layers": 4, "window": 8}),  # 2 attention groups
+    ("whisper-base", {})], ids=["dense", "moe", "hybrid-ring", "audio"])
+def test_decode_writes_cache_in_place(arch, changes):
+    """The layer scan that carries the stacked caches returns the logits and
+    caches of the scan over per-layer slices, step after step, with at least
+    two layers in every stack; the hybrid's ring buffer of 8 slots wraps
+    past its window."""
+    from repro.serving.engine import ServeEngine
+
+    cfg = dataclasses.replace(tiny_variant(get_config(arch)), **changes)
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    batch = make_batch(cfg, jax.random.PRNGKey(1))
+    prompt = batch["tokens"][:, :8]
+    _, cache = prefill(params, cfg, RUN, prompt,
+                       frontend=batch.get("frontend"))
+    engine = ServeEngine(cfg, params, run=RUN, batch_size=B)
+    cache = engine._grow_cache(cache, prompt.shape[1] + 12, B)
+    new = jax.jit(lambda c, t: decode_step(params, cfg, RUN, c, t))
+    old = jax.jit(lambda c, t: _xs_decode_step(params, cfg, RUN, c, t))
+    tok = prompt[:, -1:]
+    for _ in range(12):
+        got_logits, got = new(cache, tok)
+        want_logits, want = old(cache, tok)
+        np.testing.assert_array_equal(np.asarray(got_logits),
+                                      np.asarray(want_logits))
+        assert got.keys() == want.keys()
+        for key in want:
+            np.testing.assert_array_equal(np.asarray(got[key]),
+                                          np.asarray(want[key]), err_msg=key)
+        cache, tok = got, jnp.argmax(got_logits, -1).astype(jnp.int32)
 
 
 def test_serve_engine_mixed_prompt_lengths_match_forward():

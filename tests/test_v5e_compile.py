@@ -11,6 +11,7 @@ TPU library.
 import dataclasses
 import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -103,6 +104,120 @@ def test_decode_step_bracket_on_v5e(one_chip):
     tp, cp = report.tp_block, report.cp_block
     assert math.isfinite(tp) and math.isfinite(cp)
     assert 0.0 < tp <= cp
+
+
+@pytest.mark.parametrize("name,n_layers,cache_len", [
+    ("starcoder2-15b", 2, 1027),  # dense, G = 12, a cache no block divides
+    ("qwen3-8b", 2, 1280),  # dense, 8 KV heads, qk-norm
+    ("phi-3-vision-4.2b", 2, 1280),  # vlm, 32 KV heads of 96
+    ("deepseek-moe-16b", 2, 1280),  # moe, a dense layer first, 16 KV heads
+    ("phi3.5-moe-42b-a6.6b", 2, 1280),  # moe, 8 KV heads
+    ("whisper-base", 2, 448),  # audio self-attention, 8 KV heads of 64
+    ("zamba2-2.7b", 12, 4100),  # hybrid: 2 groups, a ring of 4096 x 32 x 80
+])
+def test_decode_step_kernel_per_family_on_v5e(one_chip, name, n_layers,
+                                              cache_len):
+    """Each attention family's decode, at its published widths, compiles
+    for a v5e with the flash-decode kernel reading its cache: the kernel's
+    blocks fit VMEM at every KV-head count and head width."""
+    from repro.kernels.decode_attention import NAME
+    from repro.models import decode_step, init_cache
+    from repro.train.state import abstract_train_state
+
+    cfg = dataclasses.replace(get_config(name), n_layers=n_layers)
+    run = RunConfig(attention_impl="chunked", attention_chunk=512,
+                    remat="none", zero=False)
+    b = 8
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: _sds(one_chip, x.shape, x.dtype), tree)
+
+    params = on_chip(abstract_train_state(cfg).params)
+    cache = on_chip(jax.eval_shape(lambda: init_cache(cfg, b, cache_len)))
+    text = jax.jit(lambda p, c, tok: decode_step(p, cfg, run, c, tok)).lower(
+        params, cache, _sds(one_chip, (b, 1), jnp.int32)).compile().as_text()
+    assert any(NAME in line and 'custom_call_target="tpu_custom_call"' in line
+               for line in text.splitlines())
+
+
+def _computations(text):
+    """{computation name: its instruction lines} of a compiled module."""
+    comps, cur = {}, None
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY )?%?([\w.\-]+) .*\{$", line)
+        if head:
+            cur = comps.setdefault(head.group(1), [])
+        elif cur is not None and line.strip() not in ("", "}"):
+            cur.append(line.strip())
+    return comps
+
+
+def _instr(line):
+    """(name, opcode, [array dims of the result]) of one instruction."""
+    m = re.match(r"^(?:ROOT )?%(\S+) = (.*?) ([\w-]+)\(", line)
+    if not m:
+        return None
+    dims = [tuple(int(d) for d in ds.split(",") if d)
+            for ds in re.findall(r"\w+\[([\d,]*)\]", m.group(2))]
+    return m.group(1), m.group(3), dims
+
+
+def test_stacked_decode_in_place_on_v5e(one_chip):
+    """Decode at yi-9b widths (2 layers, the serve cell's batch 64 and cache
+    1280) reads and writes its stacked KV cache in place: the flash-decode
+    kernel runs by name inside the layer loop, nothing in the loop makes a
+    tensor of a layer's cache or of the stack besides the in-place
+    one-row updates, and the only whole-cache copies are K and V on entry
+    (the jit is not donated its cache).  At a stack of a few tens of MB the
+    compiler may stage it in VMEM instead, so the cell's batch is kept."""
+    from repro.kernels.decode_attention import NAME
+    from repro.models import decode_step, init_cache
+    from repro.train.state import abstract_train_state
+
+    cfg = dataclasses.replace(get_config("yi-9b"), n_layers=2)
+    run = RunConfig(attention_impl="chunked", attention_chunk=512,
+                    remat="none", zero=False)
+    b, t = 64, 1280
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: _sds(one_chip, x.shape, x.dtype), tree)
+
+    params = on_chip(abstract_train_state(cfg).params)
+    cache = on_chip(jax.eval_shape(lambda: init_cache(cfg, b, t)))
+    text = jax.jit(lambda p, c, tok: decode_step(p, cfg, run, c, tok)).lower(
+        params, cache, _sds(one_chip, (b, 1), jnp.int32)).compile().as_text()
+    comps = _computations(text)
+    stack = cache["k"].shape
+
+    def squeezed(dims):
+        return tuple(sorted(d for d in dims if d != 1))
+
+    cache_sized = {squeezed(stack), squeezed(stack[1:])}  # stack, a layer
+
+    def in_place_update(op, line):
+        if op == "dynamic-update-slice":
+            return True
+        called = re.search(r"calls=%?([\w.\-]+)", line)
+        return op == "fusion" and called is not None and any(
+            l.startswith("ROOT") and " dynamic-update-slice(" in l
+            for l in comps[called.group(1)])
+
+    loops = [re.search(r"body=%?([\w.\-]+)", l).group(1)
+             for lines in comps.values() for l in lines if " while(" in l]
+    body = [i + (line,) for loop in loops for line in comps[loop]
+            if (i := _instr(line))]
+    assert any(NAME in name and op == "custom-call"
+               for name, op, _, _ in body)
+    made = [name for name, op, dims, line in body
+            if op not in ("parameter", "get-tuple-element", "tuple", "bitcast")
+            and not in_place_update(op, line)
+            and any(squeezed(d) in cache_sized for d in dims)]
+    assert made == [], made
+    instrs = [i for lines in comps.values() for i in map(_instr, lines) if i]
+    whole = [name for name, op, dims in instrs
+             if op in ("copy", "copy-start")
+             and any(squeezed(d) == squeezed(stack) for d in dims)]
+    assert len(whole) <= 2, whole
 
 
 HBM = 15.75 * 2**30  # one v5e chip, as its compiler counts it

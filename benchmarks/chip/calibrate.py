@@ -12,7 +12,8 @@ For each seed it prints one JSON line:
   float32 reference), of the control (the reference computed in float8,
   e4m3 scaled per tensor: the gap of the token it puts first at each of the
   same positions), and of the fault "a decode step that returns its cache
-  unchanged" planted in the engine (the same job served again).
+  unchanged" planted in the engine (``stale_cache``; the same job served
+  again).
 - ``train``: ``loss``, ``grad``, ``delta`` (``reference.train.compare``)
   of the program's first steps, of the control (the reference computed in
   bfloat16, parameters held in bfloat16), and of the fault "half of the
@@ -39,6 +40,19 @@ for extra in (ROOT / "src", ROOT):
 from benchmarks.chip import harness, traffic, weights  # noqa: E402
 
 
+def stale_cache(decode):
+    """``decode`` with the fault "a decode step that returns its cache
+    unchanged": it hands back a copy of the cache taken before the step,
+    so the fault holds where decode donates (and so deletes) its cache."""
+    import jax
+    import jax.numpy as jnp
+
+    def stale(params, cache, tokens):
+        kept = jax.tree.map(jnp.copy, cache)
+        return decode(params, cache, tokens)[0], kept
+    return stale
+
+
 def serve_readings(ctx, seeds):
     import jax
     import jax.numpy as jnp
@@ -56,7 +70,7 @@ def serve_readings(ctx, seeds):
             engine.params = params
         prompts, results, _, _ = drv.serve(engine, t, m["vocab"], seed, 0)
         decode = engine.decode
-        engine.decode = lambda p, c, tok: (decode(p, c, tok)[0], c)
+        engine.decode = stale_cache(decode)
         _, stale, _, _ = drv.serve(engine, t, m["vocab"], seed, 0)
         engine.decode = decode
         pick = traffic.sample(seed, len(results), t["check_requests"])

@@ -1,17 +1,20 @@
 """The harness's parts that runners, readers and tests share: finding the
-chip, loading a cell by name, the runner's context and outcome, and the
-result line."""
+chip, loading a cell by name, a model family's file by name, the runner's
+context and outcome, and the result line."""
 
 from __future__ import annotations
 
+import functools
 import importlib.util
 import json
 from dataclasses import dataclass, field
 from pathlib import Path
-from typing import Dict, List, Optional
+from types import ModuleType
+from typing import Dict, List, Mapping, Optional
 
 HERE = Path(__file__).resolve().parent
 ROOT = HERE.parents[1]
+FAMILIES = HERE / "reference"  # <family>.py: what it knows of a family
 
 PLATFORM = "tpu"  # what the run must find; the CPU rehearsal test steers it
 CACHE_DIR = ROOT / ".jax_cache"  # fixed: part of the compile cache's key
@@ -118,6 +121,30 @@ def read_metric(name: str, reading) -> Optional[float]:
     module = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(module)
     return module.read(reading)
+
+
+def family(m: Mapping) -> ModuleType:
+    """The family file of a configuration's ``model`` block,
+    ``reference/<family>.py`` by the program's ``ModelConfig.family``: its
+    parameter leaves (``leaves``, ``weights.Leaf``), its counts over the
+    whole stack (``stack_params``, ``matmul_params``, ``mixer_flops``,
+    ``decode_mixer_flops``, ``decode_state_bytes``), its plain reference
+    (``logits`` for serving, ``loss`` for training) and its CPU sizes
+    (``TINY``)."""
+    return _load_family(FAMILIES / f"{m['family']}.py")
+
+
+@functools.lru_cache(maxsize=None)
+def _load_family(path: Path) -> ModuleType:
+    if not path.is_file():
+        raise FileNotFoundError(
+            f"model family {path.stem!r} has no family file: {path} is "
+            f"missing")
+    spec = importlib.util.spec_from_file_location(
+        "chip_family_" + path.stem, path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
 
 
 @dataclass

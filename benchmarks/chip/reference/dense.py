@@ -1,4 +1,9 @@
-"""Float32 reference of a Llama-architecture decoder (Yi: arXiv:2403.04652).
+"""The dense family: a Llama-architecture decoder (Yi: arXiv:2403.04652).
+
+What the harness knows of the family: its parameter leaves (``leaves``),
+its counts over the stack (``stack_params`` ... ``decode_state_bytes``,
+which ``flops.py`` adds to the embedding and head), its float32 reference
+(``logits``) and its CPU sizes (``TINY``).
 
 Pre-norm blocks: RMSNorm, grouped-query attention with rotary position
 embedding (the rotate-half form: the first and second halves of each head
@@ -25,6 +30,75 @@ import jax.numpy as jnp
 from benchmarks.chip import weights
 from benchmarks.chip.reference.numerics import (
     einsum, masked_logits, matmul, rms_norm, silu)
+from benchmarks.chip.weights import Leaf
+
+# The query and key projections are drawn N(0, QK_SPREAD / d_model), so that
+# attention scores spread by ~QK_SPREAD as a trained model's heads do (at
+# 0.02 they spread by 0.02^2 d_model, 1.6 at Yi's width, and attention over
+# a long prompt is nearly uniform: a decode that read a stale cache would
+# then give nearly the same logits).
+QK_SPREAD = 4.0  # standard deviation of the attention scores q.k / sqrt(d)
+
+# Widths and lengths small enough for the CPU rehearsal.
+TINY = {"n_layers": 2, "d_model": 512, "n_heads": 4, "n_kv_heads": 2,
+        "d_head": 128, "d_ff": 1024, "vocab": 1000}
+
+
+def leaves(m: Mapping) -> dict:
+    """Each layer's leaves, stacked ``n_layers`` deep."""
+    d, ff = m["d_model"], m["d_ff"]
+    hq, hkv = m["n_heads"] * m["d_head"], m["n_kv_heads"] * m["d_head"]
+    qk = ("normal", (QK_SPREAD / d) ** 0.5)
+    return {
+        ("layers", "attn", "wq"): Leaf((d, hq), qk),
+        ("layers", "attn", "wk"): Leaf((d, hkv), qk),
+        ("layers", "attn", "wv"): Leaf((d, hkv), ("normal", 0.02)),
+        ("layers", "attn", "wo"): Leaf((hq, d), ("normal", 0.02)),
+        ("layers", "mlp", "wi"): Leaf((d, 2 * ff), ("normal", 0.02)),
+        ("layers", "mlp", "wo"): Leaf((ff, d), ("normal", 0.02)),
+        ("layers", "norm1"): Leaf((d,), ("scale",)),
+        ("layers", "norm2"): Leaf((d,), ("scale",)),
+    }
+
+
+def _layer_params(m: Mapping) -> int:
+    d = m["d_model"]
+    hq = m["n_heads"] * m["d_head"]
+    hkv = m["n_kv_heads"] * m["d_head"]
+    attn = d * hq + 2 * d * hkv + hq * d
+    mlp = 3 * d * m["d_ff"]  # SwiGLU: gate, up, down
+    return attn + mlp + 2 * d  # + two RMSNorm scales
+
+
+def stack_params(m: Mapping) -> int:
+    """Parameters of every layer."""
+    return m["n_layers"] * _layer_params(m)
+
+
+def matmul_params(m: Mapping) -> int:
+    """Parameters a token reads in matrix products, every layer: all but
+    the norms' scales."""
+    return m["n_layers"] * (_layer_params(m) - 2 * m["d_model"])
+
+
+def mixer_flops(m: Mapping, keys: float) -> float:
+    """Attention's scores and weighted sum of one query over ``keys`` keys,
+    all layers."""
+    hq = m["n_heads"] * m["d_head"]
+    return m["n_layers"] * 4.0 * hq * keys
+
+
+def decode_mixer_flops(m: Mapping, cache_len: int) -> float:
+    """A decode row's attention over ``cache_len`` positions (its own
+    included), all layers."""
+    return mixer_flops(m, cache_len)
+
+
+def decode_state_bytes(m: Mapping, cache_len: int, cache_bytes: int) -> int:
+    """A decode row's KV cache traffic, all layers: the valid prefix
+    (``cache_len - 1`` positions) read and the new position written."""
+    per_pos = 2 * m["n_layers"] * m["n_kv_heads"] * m["d_head"] * cache_bytes
+    return per_pos * cache_len
 
 
 def rope(x, theta):

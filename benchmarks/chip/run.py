@@ -4,10 +4,19 @@
         --seconds <s> --trace <0|1>
 
 The cell is looked up by name in ``BENCHMARK.json`` at the root of the
-checkout; its configuration (``configs/<config>.json``), its traffic
-(``traffic/<cell>.json``, whose ``kind`` names the runner in ``runners/``)
-and its per-layer metrics (``metrics/<metric>.py``) are found by name, so a
-new cell, mix or metric is a new file and a new entry, never an edit here.
+checkout; its configuration (``configs/<config>.json``), its model family's
+file (``reference/<family>.py``, by the configuration's ``model.family``:
+the family's parameter leaves, counts, plain reference and CPU sizes), its
+traffic (``traffic/<cell>.json``, whose ``kind`` names the runner in
+``runners/``) and its per-layer metrics (``metrics/<metric>.py``) are found
+by name.  So a new configuration adds, and edits no file that is there:
+
+- ``configs/<config>.json``, and its entry under ``configs``;
+- ``reference/<family>.py``, where its family has no file yet;
+- for each of its cells ``traffic/<cell>.json``, and an entry under
+  ``workloads``;
+- for each new per-layer metric ``metrics/<metric>.py``, and an entry under
+  ``per_layer``.
 
 One process, on the machine it is started on: it finds the TPU on the PCI
 bus before any backend starts and asks JAX for it whatever

@@ -12,9 +12,10 @@ End to end: generated tokens of all jobs over the window's time, and the
 
 Correct: once the window has closed and the program's weights are freed, a
 sample of the requests (drawn from the seed; every request has the same
-length) goes through the float32 reference: prompt plus served tokens, one
-causal pass, and at each served position the gap by which the served
-token's logit lies below the reference's best.  The widest gap is held to
+length) goes through the float32 reference of the model's family
+(``logits`` in its family file): prompt plus served tokens, one causal
+pass, and at each served position the gap by which the served token's
+logit lies below the reference's best.  The widest gap is held to
 the traffic file's limit.
 """
 
@@ -29,8 +30,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.profiler import TraceAnnotation
 
-from benchmarks.chip import flops, trace, traffic, weights
-from benchmarks.chip.reference import dense
+from benchmarks.chip import flops, harness, trace, traffic, weights
 
 WARM_JOB = 2**31 - 1  # a job index the window never reaches
 
@@ -71,7 +71,8 @@ def reference_gaps(m, root, prompts, served, precisions=("f32",)):
     p_len = len(prompts[0])
     seqs = np.asarray([p + s[:-1] for p, s in zip(prompts, served)],
                       np.int32)
-    out = dense.logits(m, root, seqs, p_len - 1, jnp.bfloat16, precisions)
+    out = harness.family(m).logits(m, root, seqs, p_len - 1, jnp.bfloat16,
+                                   precisions)
     ref = out["f32"]
     best = jnp.max(ref, axis=-1)
     gaps = {"f32": best - jnp.take_along_axis(
@@ -85,8 +86,6 @@ def reference_gaps(m, root, prompts, served, precisions=("f32",)):
 
 
 def run(ctx):
-    from benchmarks.chip.harness import Outcome, memory_peak
-
     t, m = ctx.traffic, ctx.model
     root, params, engine = build(ctx)
     serve(engine, t, m["vocab"], ctx.seed, WARM_JOB)  # compiles every shape
@@ -108,7 +107,7 @@ def run(ctx):
         while time.perf_counter() - t_first < ctx.seconds:
             jobs.append(serve(engine, t, m["vocab"], ctx.seed, len(jobs)))
     t_end = jobs[-1][3]
-    peak = memory_peak(ctx.devices)
+    peak = harness.memory_peak(ctx.devices)
 
     n_new = t["new_tokens"]
     requests = [(prompts[i], r, sub, done)
@@ -138,9 +137,9 @@ def run(ctx):
     t_ref = time.perf_counter()
     gap = float(reference_gaps(m, root, prompts, served)["f32"].max()) \
         if served else float("inf")
-    return Outcome(attempted=attempted, failed=failed,
-                   setup_s=t_first - ctx.t_start,
-                   checks={"gap": (gap, t["limits"]["gap"])},
-                   values=values, reduced=reduced, counters=counters,
-                   memory_peak_bytes=peak,
-                   reference_s=time.perf_counter() - t_ref)
+    return harness.Outcome(attempted=attempted, failed=failed,
+                           setup_s=t_first - ctx.t_start,
+                           checks={"gap": (gap, t["limits"]["gap"])},
+                           values=values, reduced=reduced, counters=counters,
+                           memory_peak_bytes=peak,
+                           reference_s=time.perf_counter() - t_ref)

@@ -13,10 +13,11 @@ End to end: tokens of every step completed in the window over the window's
 time (all chips together).
 
 Correct: once the window has closed and the program's state is freed, the
-float32 reference (``reference/``) takes the same steps from the same
-parameters on the same batches (rebuilt by the benchmark's own copy of the
-token stream), and the three numbers of ``reference.train.compare`` are
-held to the traffic file's limits.
+float32 reference (``reference/train.py``, with the ``loss`` of the model's
+family file) takes the same steps from the same parameters on the same
+batches (rebuilt by the benchmark's own copy of the token stream), and the
+three numbers of ``reference.train.compare`` are held to the traffic file's
+limits.
 """
 
 from __future__ import annotations
@@ -30,11 +31,8 @@ import jax
 import jax.numpy as jnp
 from jax.profiler import TraceAnnotation
 
-from benchmarks.chip import flops, trace, traffic, weights
-from benchmarks.chip.reference import mamba2
+from benchmarks.chip import flops, harness, trace, traffic, weights
 from benchmarks.chip.reference import train as ref_train
-
-REFERENCE_LOSS = {"ssm": mamba2.loss}
 
 
 def build(ctx):
@@ -95,7 +93,6 @@ def step_once(compiled, state, pipeline):
 
 
 def run(ctx):
-    from benchmarks.chip.harness import Outcome, memory_peak
     from repro.distributed import set_mesh_context
 
     t, m = ctx.traffic, ctx.model
@@ -122,7 +119,7 @@ def run(ctx):
                 state, loss = step_once(compiled, state, pipeline)
                 losses.append(loss)
         window = time.perf_counter() - t_first
-        peak = memory_peak(ctx.devices)
+        peak = harness.memory_peak(ctx.devices)
     finally:
         pipeline.close()
         set_mesh_context(None)
@@ -140,11 +137,12 @@ def run(ctx):
     numbers = ref_train.compare(prog, reference_run(ctx, root))
     checks = {k: (numbers[k], t["limits"][k]) for k in ("loss", "grad",
                                                           "delta")}
-    return Outcome(attempted=len(losses), failed=len(losses) - len(done),
-                   setup_s=t_first - ctx.t_start, checks=checks,
-                   values=values, reduced=reduced, counters=counters,
-                   memory_peak_bytes=peak,
-                   reference_s=time.perf_counter() - t_ref)
+    return harness.Outcome(attempted=len(losses),
+                           failed=len(losses) - len(done),
+                           setup_s=t_first - ctx.t_start, checks=checks,
+                           values=values, reduced=reduced, counters=counters,
+                           memory_peak_bytes=peak,
+                           reference_s=time.perf_counter() - t_ref)
 
 
 def reference_batches(ctx):
@@ -157,7 +155,7 @@ def reference_run(ctx, root, precision="f32", half_batch=False):
     """The reference's steps from the seed: loss, first gradient and change
     of each leaf (``reference.train.follow``)."""
     m = ctx.model
-    loss_fn = partial(REFERENCE_LOSS[m["family"]], m=m, precision=precision)
+    loss_fn = partial(harness.family(m).loss, m=m, precision=precision)
     params0 = weights.params_fn(m, jnp.float32)(root)
     return ref_train.follow(params0, reference_batches(ctx),
                             lambda p, x, y: loss_fn(p, x, y),

@@ -22,22 +22,52 @@ def _context(cell, model, traffic):
     return harness.Context(c, 0, 0.0, False, jax.devices()[:1], {}, 0.0)
 
 
+def _serve_context():
+    # Four layers at a quarter of Yi's width (the head scaled to Yi's logit
+    # spread by ``full_width_logits``); the float8 control's rounding goes
+    # through fewer and narrower layers, so its gap is smaller than at full
+    # size.
+    return _context("yi9b-serve-offline",
+                    {"n_layers": 4, "d_model": 1024, "n_heads": 8,
+                     "n_kv_heads": 2, "d_head": 128, "d_ff": 2048,
+                     "vocab": 4000},
+                    {"batch": 2, "prompt_len": 32, "new_tokens": 16,
+                     "check_requests": 2})
+
+
 def test_serve_control_reads_far_above_the_program(monkeypatch):
-    # Four layers at a quarter of Yi's width, the head scaled to Yi's logit
-    # spread; the float8 control's rounding goes through fewer and narrower
-    # layers, so its gap is smaller than at full size.
     full_width_logits(monkeypatch)
-    ctx = _context("yi9b-serve-offline",
-                   {"n_layers": 4, "d_model": 1024, "n_heads": 8,
-                    "n_kv_heads": 2, "d_head": 128, "d_ff": 2048,
-                    "vocab": 4000},
-                   {"batch": 2, "prompt_len": 32, "new_tokens": 16,
-                    "check_requests": 2})
+    ctx = _serve_context()
     limit = ctx.traffic["limits"]["gap"]
     for r in calibrate.serve_readings(ctx, SEEDS):
         assert r["program"]["gap"] <= limit / 5, r
         assert r["control"]["gap"] >= 10 * max(r["program"]["gap"], 0.05), r
         assert r["stale_cache"]["gap"] > limit, r
+
+
+def test_stale_cache_fault_holds_where_decode_donates_its_cache(monkeypatch):
+    """A decode jitted with ``donate_argnums=(1,)`` deletes the cache it is
+    given: the fault must hand back a copy taken before the step."""
+    from repro.models import decode_step
+    from repro.serving import ServeEngine
+
+    full_width_logits(monkeypatch)
+    seeds = SEEDS[1:2]
+    kept = list(calibrate.serve_readings(_serve_context(), seeds))
+    init = ServeEngine.__init__
+
+    def donating(self, *a, **kw):
+        init(self, *a, **kw)
+        self.decode = jax.jit(
+            lambda p, c, t: decode_step(p, self.cfg, self.run, c, t),
+            donate_argnums=(1,))
+    monkeypatch.setattr(ServeEngine, "__init__", donating)
+    ctx = _serve_context()
+    donated = list(calibrate.serve_readings(ctx, seeds))
+    limit = ctx.traffic["limits"]["gap"]
+    for k, d in zip(kept, donated):
+        assert d["program"] == k["program"], (k, d)
+        assert d["stale_cache"]["gap"] > limit, d
 
 
 def test_train_control_and_fault_fail_the_limits():

@@ -69,12 +69,17 @@ def test_ssm_small_shape_by_hand():
 
 
 def test_counts_come_from_no_program_module():
-    tree = ast.parse((CHIP / "flops.py").read_text())
-    imported = {alias.name for node in ast.walk(tree)
-                if isinstance(node, ast.Import) for alias in node.names}
-    imported |= {node.module for node in ast.walk(tree)
-                 if isinstance(node, ast.ImportFrom) and node.module}
-    assert not any(name.startswith("repro") for name in imported), imported
+    # flops.py and every family file (its counts, weights and reference)
+    files = [CHIP / "flops.py", *sorted((CHIP / "reference").glob("*.py"))]
+    assert len(files) >= 3
+    for path in files:
+        tree = ast.parse(path.read_text())
+        imported = {alias.name for node in ast.walk(tree)
+                    if isinstance(node, ast.Import) for alias in node.names}
+        imported |= {node.module for node in ast.walk(tree)
+                     if isinstance(node, ast.ImportFrom) and node.module}
+        assert not any(name.startswith("repro") for name in imported), \
+            (path.name, imported)
 
 
 def test_peaks_hold_the_v5e_with_a_source():

@@ -3,9 +3,10 @@
 Every cell of ``BENCHMARK.json`` loads its configuration and traffic by
 name and runs once, in this process, with the chip check steered here (the
 harness's ``tpu_chips_on_bus`` and ``PLATFORM``; its compile cache left
-off) and the sizes cut to a few layers and a few tokens.  The last line it
-prints has the contract's shape.  Then the timed path is broken underneath
-in each way its cell can be broken, and ``correct`` must come out false.
+off) and the sizes cut to a few layers and a few tokens (the family file's
+``TINY`` widths).  The last line it prints has the contract's shape.  Then
+the timed path is broken underneath in each way its cell can be broken, and
+``correct`` must come out false.
 """
 
 import json
@@ -17,19 +18,14 @@ from pathlib import Path
 import jax
 import pytest
 
-from benchmarks.chip import harness, weights
+from benchmarks.chip import calibrate, harness, weights
 from benchmarks.chip import run as run_mod
 
 ROOT = Path(__file__).resolve().parents[3]
 BENCH = json.loads((ROOT / "BENCHMARK.json").read_text())
 CELLS = [w["name"] for w in BENCH["workloads"]]
 
-# Widths and lengths small enough for the CPU.  The head's weights are
-# scaled so that its logits spread as Yi's do (0.02 * sqrt(4096) = 1.28):
-# the cell's limit on the logit gap is in those units.
-TINY_MODEL = {"n_layers": 2, "d_model": 512, "n_heads": 4, "n_kv_heads": 2,
-              "d_head": 128, "d_ff": 1024, "vocab": 1000, "ssm_state": 16,
-              "ssm_head_dim": 32, "ssm_chunk": 32}
+# Lengths small enough for the CPU (the widths are each family file's TINY).
 TINY_TRAFFIC = {"batch": 2, "prompt_len": 16, "new_tokens": 12,
                 "check_requests": 2, "trace_jobs": 1, "seq": 64,
                 "trace_steps": 2}
@@ -37,24 +33,30 @@ SEED = 2**33 + 7  # wider than 32 bits, as a run's seed may be
 LOAD_PEAKS = harness.load_peaks
 
 
+def tiny_model(m):
+    """A model block at its family's CPU sizes."""
+    return dict(m, **harness.family(m).TINY)
+
+
 def tiny(cell):
-    m = dict(cell.config["model"])
-    m.update({k: v for k, v in TINY_MODEL.items() if m.get(k)})
-    cell.config = dict(cell.config, model=m)
+    cell.config = dict(cell.config, model=tiny_model(cell.config["model"]))
     cell.traffic = dict(cell.traffic, **{
         k: v for k, v in TINY_TRAFFIC.items() if k in cell.traffic})
     return cell
 
 
 def full_width_logits(monkeypatch):
+    """Scale the head's weights so that its logits spread as Yi's do
+    (0.02 * sqrt(4096) = 1.28): the cell's limit on the logit gap is in
+    those units."""
     spec = weights._leaf_spec
 
     def scaled(m):
         out = dict(spec(m))
         if ("lm_head",) in out:
-            shape, _, stacked = out[("lm_head",)]
+            shape, _, copies = out[("lm_head",)]
             std = 0.02 * (4096 / m["d_model"]) ** 0.5
-            out[("lm_head",)] = (shape, ("normal", std), stacked)
+            out[("lm_head",)] = (shape, ("normal", std), copies)
         return out
     monkeypatch.setattr(weights, "_leaf_spec", scaled)
 
@@ -84,7 +86,6 @@ def run_cell(capsys, cell, trace=0, seconds=1.0):
 def test_every_cell_names_files_that_exist():
     for w in BENCH["workloads"]:
         cell = harness.load_cell(w["name"])
-        assert cell.traffic["kind"] in ("serve_offline", "train")
         assert (ROOT / "benchmarks/chip/runners" /
                 f"{cell.traffic['kind']}.py").exists()
         assert set(cell.traffic["limits"])
@@ -189,8 +190,7 @@ def _decode_keeps_its_cache(monkeypatch):
 
     def frozen(self, *a, **kw):
         init(self, *a, **kw)
-        decode = self.decode
-        self.decode = lambda p, c, t: (decode(p, c, t)[0], c)
+        self.decode = calibrate.stale_cache(self.decode)
     monkeypatch.setattr(ServeEngine, "__init__", frozen)
 
 
@@ -228,7 +228,8 @@ FAULTS = {
     "train": [_step_keeps_its_state, _step_sees_half_the_batch],
 }
 CASES = [(cell, fault) for cell in CELLS
-         for fault in FAULTS[harness.load_cell(cell).traffic["kind"]]]
+         for fault in FAULTS.get(harness.load_cell(cell).traffic["kind"],
+                                 ())]
 
 
 @pytest.mark.parametrize(

@@ -1,36 +1,31 @@
 """Weights from the seed, in the program's parameter layout.
 
-Every leaf is drawn from its own key, ``fold_in(fold_in(root, leaf), layer)``,
+Every leaf is drawn from its own key, ``fold_in(fold_in(root, leaf), copy)``,
 so one layer's slice can be drawn again alone: the program gets the whole
 tree from one jitted call (``make_params``), and a reference draws layer by
 layer (``layer_params``) the same numbers without holding the whole model
 and without taking anything from the program.
 
-Distributions follow the published initialisations where they matter to
-the numerics: matrices N(0, 0.02^2); RMSNorm scales 1 + N(0, 0.1^2), so that
-a scale applied wrongly shows; the query and key projections
-N(0, QK_SPREAD / d_model), so that attention scores spread by ~QK_SPREAD as
-a trained model's heads do (at 0.02 they spread by 0.02^2 d_model, 1.6 at
-Yi's width, and attention over a long prompt is nearly uniform: a decode
-that read a stale cache would then give nearly the same logits); Mamba-2's
-depthwise convolution U(-1/sqrt(K), 1/sqrt(K)), A = -U(1, 16) (``A_log`` =
-log), dt from log-uniform [1e-3, 1e-1] stored as its inverse softplus
-(``dt_bias``), D = 1.
+The embedding, the final norm and the untied head are every family's and
+are kept here; the other leaves are the family's (``leaves`` in its family
+file, ``reference/<family>.py``), each with its shape, its law and how many
+copies are stacked.  Distributions follow the published initialisations
+where they matter to the numerics: matrices N(0, 0.02^2); RMSNorm scales
+1 + N(0, 0.1^2), so that a scale applied wrongly shows; the family files
+give the rest.
 """
 
 from __future__ import annotations
 
 import zlib
 from functools import partial
-from typing import Dict, Mapping, Tuple
+from typing import Dict, Mapping, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from benchmarks.chip import flops
-
-QK_SPREAD = 4.0  # standard deviation of the attention scores q.k / sqrt(d)
+from benchmarks.chip import flops, harness
 
 
 def root_key(seed: int) -> jax.Array:
@@ -40,51 +35,34 @@ def root_key(seed: int) -> jax.Array:
                                     impl="threefry2x32")
 
 
+class Leaf(NamedTuple):
+    """A family's parameter leaf: the shape of one copy, its law (one of
+    ``_draw``'s, or a function ``(key, shape) -> float32 array``), and how
+    many copies are stacked on a leading axis (None: one a layer,
+    ``n_layers``; 0: one leaf, not stacked)."""
+    shape: tuple
+    law: object
+    copies: Optional[int] = None
+
+
 def _leaf_spec(m: Mapping) -> Dict[Tuple[str, ...], tuple]:
-    """path -> (shape of one layer or of the whole leaf, law, stacked)."""
+    """path -> (shape of one copy, law, copies stacked; 0: not stacked)."""
     d, vp = m["d_model"], flops.padded_vocab(m)
     spec: Dict[Tuple[str, ...], tuple] = {
-        ("embed",): ((vp, d), ("normal", 0.02), False),
-        ("final_norm",): ((d,), ("scale",), False),
+        ("embed",): ((vp, d), ("normal", 0.02), 0),
+        ("final_norm",): ((d,), ("scale",), 0),
     }
     if not m.get("tie_embeddings", False):
-        spec[("lm_head",)] = ((d, vp), ("normal", 0.02), False)
-    if m["family"] == "dense":
-        hq, hkv = m["n_heads"] * m["d_head"], m["n_kv_heads"] * m["d_head"]
-        ff = m["d_ff"]
-        qk = ("normal", (QK_SPREAD / d) ** 0.5)
-        spec.update({
-            ("layers", "attn", "wq"): ((d, hq), qk, True),
-            ("layers", "attn", "wk"): ((d, hkv), qk, True),
-            ("layers", "attn", "wv"): ((d, hkv), ("normal", 0.02), True),
-            ("layers", "attn", "wo"): ((hq, d), ("normal", 0.02), True),
-            ("layers", "mlp", "wi"): ((d, 2 * ff), ("normal", 0.02), True),
-            ("layers", "mlp", "wo"): ((ff, d), ("normal", 0.02), True),
-            ("layers", "norm1"): ((d,), ("scale",), True),
-            ("layers", "norm2"): ((d,), ("scale",), True),
-        })
-    elif m["family"] == "ssm":
-        di = m.get("ssm_expand", 2) * d
-        n, p = m["ssm_state"], m.get("ssm_head_dim", 64)
-        nh, k = di // p, m.get("ssm_conv", 4)
-        spec.update({
-            ("layers", "mamba", "in_proj"):
-                ((d, 2 * di + 2 * n + nh), ("normal", 0.02), True),
-            ("layers", "mamba", "conv_w"):
-                ((k, di + 2 * n), ("uniform", k ** -0.5), True),
-            ("layers", "mamba", "A_log"): ((nh,), ("a_log",), True),
-            ("layers", "mamba", "D"): ((nh,), ("ones",), True),
-            ("layers", "mamba", "dt_bias"): ((nh,), ("dt_bias",), True),
-            ("layers", "mamba", "ssm_norm"): ((di,), ("scale",), True),
-            ("layers", "mamba", "out_proj"): ((di, d), ("normal", 0.02), True),
-            ("layers", "norm1"): ((d,), ("scale",), True),
-        })
-    else:
-        raise ValueError(f"no weights for family {m['family']!r}")
+        spec[("lm_head",)] = ((d, vp), ("normal", 0.02), 0)
+    for path, leaf in harness.family(m).leaves(m).items():
+        copies = m["n_layers"] if leaf.copies is None else leaf.copies
+        spec[path] = (leaf.shape, leaf.law, copies)
     return spec
 
 
 def _draw(key, shape, law):
+    if callable(law):
+        return law(key, shape)
     kind = law[0]
     if kind == "normal":
         return jax.random.normal(key, shape, jnp.float32) * law[1]
@@ -94,18 +72,12 @@ def _draw(key, shape, law):
         return jnp.ones(shape, jnp.float32)
     if kind == "uniform":
         return jax.random.uniform(key, shape, jnp.float32, -law[1], law[1])
-    if kind == "a_log":
-        return jnp.log(jax.random.uniform(key, shape, jnp.float32, 1.0, 16.0))
-    if kind == "dt_bias":
-        dt = jnp.exp(jax.random.uniform(key, shape, jnp.float32,
-                                        np.log(1e-3), np.log(1e-1)))
-        return dt + jnp.log(-jnp.expm1(-dt))  # inverse softplus
     raise ValueError(kind)
 
 
-def _leaf_key(root, path, layer):
+def _leaf_key(root, path, copy):
     tag = zlib.crc32("/".join(path).encode()) & 0x7FFFFFFF
-    return jax.random.fold_in(jax.random.fold_in(root, tag), layer)
+    return jax.random.fold_in(jax.random.fold_in(root, tag), copy)
 
 
 def _nest(flat: Dict[Tuple[str, ...], jax.Array]) -> dict:
@@ -119,14 +91,13 @@ def _nest(flat: Dict[Tuple[str, ...], jax.Array]) -> dict:
 
 
 def make_params(m: Mapping, root, dtype) -> dict:
-    """The whole parameter tree, stacked layers first, as ``dtype``.  Call
+    """The whole parameter tree, stacked copies first, as ``dtype``.  Call
     under ``jax.jit`` (``params_fn``) so it is one program on the device."""
     flat = {}
-    for path, (shape, law, stacked) in _leaf_spec(m).items():
-        if stacked:
-            layers = jnp.arange(m["n_layers"])
-            leaf = jax.vmap(lambda l, p=path, s=shape, w=law: _draw(
-                _leaf_key(root, p, l), s, w))(layers)
+    for path, (shape, law, copies) in _leaf_spec(m).items():
+        if copies:
+            leaf = jax.vmap(lambda c, p=path, s=shape, w=law: _draw(
+                _leaf_key(root, p, c), s, w))(jnp.arange(copies))
         else:
             leaf = _draw(_leaf_key(root, path, 0), shape, law)
         flat[path] = leaf.astype(dtype)
@@ -140,10 +111,10 @@ def params_fn(m: Mapping, dtype, out_shardings=None):
 
 
 @partial(jax.jit, static_argnums=(0, 2, 4))
-def _one_leaf(m_items, root, path, layer, dtype):
+def _one_leaf(m_items, root, path, copy, dtype):
     m = dict(m_items)
     shape, law, _ = _leaf_spec(m)[path]
-    return _draw(_leaf_key(root, path, layer), shape, law).astype(dtype)
+    return _draw(_leaf_key(root, path, copy), shape, law).astype(dtype)
 
 
 def frozen(m: Mapping):
@@ -152,16 +123,20 @@ def frozen(m: Mapping):
                         if isinstance(v, (int, float, str, bool))))
 
 
-def layer_params(m: Mapping, root, layer: int, dtype) -> dict:
-    """Layer ``layer``'s slice of the stacked leaves, drawn alone."""
+def layer_params(m: Mapping, root, layer: int, dtype,
+                 stack: str = "layers") -> dict:
+    """Copy ``layer`` of the leaves stacked under ``stack``, drawn alone."""
     items = frozen(m)
     flat = {path[1:]: _one_leaf(items, root, path, layer, dtype)
-            for path, (_, _, stacked) in _leaf_spec(m).items() if stacked}
+            for path, (_, _, copies) in _leaf_spec(m).items()
+            if copies and path[0] == stack}
     return _nest(flat)
 
 
 def top_params(m: Mapping, root, dtype) -> dict:
-    """The leaves outside the stack (embedding, final norm, head)."""
+    """The leaves that are not stacked (embedding, final norm, head, and any
+    of the family's)."""
     items = frozen(m)
-    return {path[0]: _one_leaf(items, root, path, 0, dtype)
-            for path, (_, _, stacked) in _leaf_spec(m).items() if not stacked}
+    return _nest({path: _one_leaf(items, root, path, 0, dtype)
+                  for path, (_, _, copies) in _leaf_spec(m).items()
+                  if not copies})

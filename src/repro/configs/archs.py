@@ -1,4 +1,4 @@
-"""The ten assigned architectures, exactly as specified in the task sheet.
+"""The assigned architectures, exactly as specified in the task sheet.
 
 Each entry records its public source. ``--arch <id>`` selects these in the
 launchers; ``tiny_variant`` derives the CPU smoke-test configs.
@@ -47,15 +47,39 @@ def qwen3() -> ModelConfig:
     )
 
 
+# Zamba2's layer patterns (config.json ``layers_block_type``): "H" is a
+# shared attention block, then a Mamba-2 layer; "M" a Mamba-2 layer alone.
+ZAMBA2_2P7B_PATTERN = "M" + "MMMMMH" * 7 + "MMMMH" + "MMMH" + "MM"
+ZAMBA2_7B_PATTERN = "MMMMMMH" + "MMMMH" + "MMMMMH" * 11 + "MMM"
+
+
 @register("zamba2-2.7b")
 def zamba2() -> ModelConfig:
-    # [arXiv:2411.15242; hf] Mamba2 backbone + shared attention block.
-    # 54L d2560 32H kv32 ff10240 v32000 ssm_state=64.
+    # [arXiv:2411.15242; hf] Mamba2 backbone + two shared attention blocks
+    # used in turn. 54L d2560 32H kv32 (heads of 2d/32 = 160) ff10240
+    # v32000 ssm_state=64.
     return ModelConfig(
         name="zamba2-2.7b", family="hybrid", n_layers=54, d_model=2560,
-        n_heads=32, n_kv_heads=32, d_head=80, d_ff=10240, vocab=32000,
-        ssm_state=64, hybrid_attn_every=6,
-        window=4096,  # long-context deployment mode for the shared attn block
+        n_heads=32, n_kv_heads=32, d_head=160, d_ff=10240, vocab=32000,
+        ssm_state=64, ssm_conv_bias=True, hybrid_pattern=ZAMBA2_2P7B_PATTERN,
+        hybrid_blocks=2, adapter_rank=128, act="geglu",
+        attn_scale="half_head", tie_embeddings=True,
+    )
+
+
+@register("zamba2-7b")
+def zamba2_7b() -> ModelConfig:
+    # [hf:Zyphra/Zamba2-7B-Instruct config.json] 81 Mamba-2 layers, 13 of
+    # them after one of 2 shared blocks. d3584 32H kv32 (heads of 224 on
+    # the 7168-wide concat) ff14336 v32000; Mamba-2 112 heads x 64, state
+    # 64, 2 groups.  The KV cache keeps its 224-lane heads in 256 lanes.
+    return ModelConfig(
+        name="zamba2-7b", family="hybrid", n_layers=81, d_model=3584,
+        n_heads=32, n_kv_heads=32, d_head=224, d_ff=14336, vocab=32000,
+        ssm_state=64, ssm_groups=2, ssm_conv_bias=True,
+        hybrid_pattern=ZAMBA2_7B_PATTERN, hybrid_blocks=2, adapter_rank=128,
+        act="geglu", attn_scale="half_head", tie_embeddings=True,
+        kv_head_align=128,
     )
 
 

@@ -33,14 +33,33 @@ class ModelConfig:
     ssm_head_dim: int = 64
     ssm_chunk: int = 256
     ssm_conv: int = 4
+    ssm_groups: int = 1  # B/C groups; head h reads group h // (heads/groups)
+    ssm_conv_bias: bool = False
 
-    # Hybrid (Zamba2): one shared attention block applied every k layers.
-    hybrid_attn_every: int = 0
+    # Hybrid (Zamba2): every layer is a Mamba-2 layer; at an "H" of
+    # ``hybrid_pattern`` (one letter a layer, "M" or "H"; a cut in depth
+    # keeps its first ``n_layers``) a shared attention block runs first,
+    # the next of ``hybrid_blocks`` in turn, and its output goes through the
+    # invocation's own linear map into that Mamba-2 layer's input.  Each
+    # invocation adds a rank-``adapter_rank`` adapter to the shared MLP's
+    # gate_up projection.
+    hybrid_pattern: str = ""
+    hybrid_blocks: int = 1
+    adapter_rank: int = 0
 
     # Attention details
     qk_norm: bool = False
     rope_theta: float = 10000.0
+    attn_scale: str = "head"  # scores times d_head**-0.5; "half_head":
+    # (d_head / 2)**-0.5, Zamba2's
     window: int = 0  # sliding window size; 0 = full causal
+    # The KV cache stores each head's d_head lanes rounded up to a multiple
+    # of this, zeros past d_head.  A TPU lays out a stack of heads that are
+    # not a multiple of 128 lanes (Zamba2's 224) with the positions minor,
+    # and the decode kernel's row-major read then copies the whole stack in
+    # and out of every step; at 128 the stack is stored as the kernel reads
+    # it.  1: d_head as is.
+    kv_head_align: int = 1
     attn_logit_softcap: float = 0.0
 
     # Encoder-decoder / modality frontends (audio/vlm backbones).
@@ -49,7 +68,7 @@ class ModelConfig:
     frontend: str = "none"  # none | audio_stub | vision_stub
     frontend_len: int = 0  # stub frames / patches per example
 
-    act: str = "swiglu"  # swiglu | gelu
+    act: str = "swiglu"  # swiglu | gelu | geglu (gated, exact erf)
     tie_embeddings: bool = False
     norm_eps: float = 1e-5
     dtype: str = "bfloat16"
@@ -69,6 +88,20 @@ class ModelConfig:
         return self.d_inner // self.ssm_head_dim
 
     @property
+    def cache_head_dim(self) -> int:
+        """A KV cache head's width: d_head rounded up to ``kv_head_align``."""
+        return -(-self.d_head // self.kv_head_align) * self.kv_head_align
+
+    @property
+    def hybrid_ids(self) -> Tuple[int, ...]:
+        """The layers a shared block runs before, in order: invocation j
+        runs block j % hybrid_blocks."""
+        pattern = self.hybrid_pattern[:self.n_layers]
+        assert len(pattern) == self.n_layers and set(pattern) <= {"M", "H"}, \
+            f"hybrid_pattern must give each of {self.n_layers} layers M or H"
+        return tuple(i for i, kind in enumerate(pattern) if kind == "H")
+
+    @property
     def is_attention_free(self) -> bool:
         return self.family == "ssm"
 
@@ -78,12 +111,14 @@ class ModelConfig:
         return self.family in ("ssm", "hybrid")
 
     def param_count(self) -> int:
-        """Approximate parameter count (used for 6ND MODEL_FLOPS)."""
+        """Approximate parameter count (used for 6ND MODEL_FLOPS); exact for
+        the hybrid family, the final norm included."""
         d, ff, v = self.d_model, self.d_ff, self.vocab
         h_q = self.n_heads * self.d_head
         h_kv = self.n_kv_heads * self.d_head
         attn = d * h_q + 2 * d * h_kv + h_q * d
-        per_dense = attn + (3 if self.act == "swiglu" else 2) * d * ff + 2 * d
+        gated = self.act in ("swiglu", "geglu")
+        per_dense = attn + (3 if gated else 2) * d * ff + 2 * d
         total = v * d  # embed
         if not self.tie_embeddings:
             total += d * v
@@ -100,12 +135,17 @@ class ModelConfig:
             per = d * (2 * di + 2 * n + self.ssm_heads) + di * d + 3 * self.ssm_heads
             total += self.n_layers * (per + d)
         elif self.family == "hybrid":
-            di, n = self.d_inner, self.ssm_state
-            per_mamba = d * (2 * di + 2 * n + self.ssm_heads) + di * d + d
+            di, nh = self.d_inner, self.ssm_heads
+            gn = self.ssm_groups * self.ssm_state
+            conv = (self.ssm_conv + self.ssm_conv_bias) * (di + 2 * gn)
+            per_mamba = d * (2 * di + 2 * gn + nh) + conv + 3 * nh + di \
+                + di * d + d
             total += self.n_layers * per_mamba
-            shared_blk = (2 * d) * h_q + 2 * (2 * d) * h_kv + h_q * d + 3 * d * ff
-            n_inv = self.n_layers // max(self.hybrid_attn_every, 1)
-            total += shared_blk + n_inv * (2 * d) * d  # + per-invocation proj
+            shared_blk = (2 * d) * h_q + 2 * (2 * d) * h_kv + h_q * d \
+                + 3 * d * ff + 3 * d
+            adapter = self.adapter_rank * (d + 2 * ff)
+            total += self.hybrid_blocks * shared_blk
+            total += len(self.hybrid_ids) * (adapter + d * d) + d
         else:
             layers = self.n_layers + self.n_encoder_layers
             total += layers * per_dense
@@ -178,6 +218,9 @@ class RunConfig:
     # so einsum stays the default; "gather" is kept as the measured
     # alternative.
     moe_dispatch: str = "einsum"
+    # Prefill a wave this many rows at a time, in one program (0: all at
+    # once): the activations of one block of rows are live at a time.
+    prefill_rows: int = 0
     compute_dtype: str = "bfloat16"
     param_dtype: str = "float32"
     grad_compression: str = "none"  # none | int8
@@ -212,12 +255,20 @@ def list_archs() -> Tuple[str, ...]:
     return tuple(sorted(_REGISTRY))
 
 
+TINY_HYBRID_PATTERN = "MHHMHH"
+
+
 def tiny_variant(cfg: ModelConfig) -> ModelConfig:
-    """Reduced same-family config for CPU smoke tests."""
+    """Reduced same-family config for CPU smoke tests.  A hybrid keeps a
+    Mamba-2 layer before its shared blocks, runs each of two blocks twice,
+    two invocations back to back, and a Mamba-2 layer after a block's."""
+    hybrid = cfg.family == "hybrid"
     return replace(
         cfg,
         name=cfg.name + "-tiny",
-        n_layers=min(cfg.n_layers, 2),
+        n_layers=len(TINY_HYBRID_PATTERN) if hybrid else min(cfg.n_layers, 2),
+        hybrid_pattern=TINY_HYBRID_PATTERN if hybrid else "",
+        hybrid_blocks=min(cfg.hybrid_blocks, 2),
         n_encoder_layers=min(cfg.n_encoder_layers, 2),
         d_model=128,
         n_heads=4,
@@ -232,7 +283,8 @@ def tiny_variant(cfg: ModelConfig) -> ModelConfig:
         ssm_state=min(cfg.ssm_state, 16) if cfg.ssm_state else 0,
         ssm_head_dim=32 if cfg.ssm_state else 64,
         ssm_chunk=32,
-        hybrid_attn_every=2 if cfg.hybrid_attn_every else 0,
+        ssm_groups=min(cfg.ssm_groups, 2),
+        adapter_rank=min(cfg.adapter_rank, 8),
         frontend_len=min(cfg.frontend_len, 16) if cfg.frontend_len else 0,
         moe_first_dense=min(cfg.moe_first_dense, 1),
     )

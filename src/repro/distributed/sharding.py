@@ -121,7 +121,7 @@ def spec_for_path(path: Tuple[str, ...], shape: Tuple[int, ...],
     if name in ("lm_head",):
         return last(m)  # (d, V)
     if name in ("wq", "wk", "wv", "wi", "w_gate_up", "in_proj", "cross_wk",
-                "cross_wv", "cross_wq"):
+                "cross_wv", "cross_wq", "adapter_up"):
         return last(m)
     if name in ("wo", "out_proj", "cross_wo"):
         return second_last(m)
@@ -131,7 +131,7 @@ def spec_for_path(path: Tuple[str, ...], shape: Tuple[int, ...],
         return P(None, m, None, None) if ndim == 4 else second_last(m)
     if name in ("router",):
         return P()
-    if name in ("conv_w", "A_log", "D", "dt_bias"):
+    if name in ("conv_w", "conv_b", "A_log", "D", "dt_bias"):
         return P()  # small SSM tensors: replicated
     # norms, scales, biases, positional tables: replicated
     return P()

@@ -133,10 +133,11 @@ def _decode_kernel(layer_ref, len_ref, qh_ref, rh_ref, q_ref, k_ref, v_ref,
 def decode_attention_stacked(
     q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, layer: jnp.ndarray,
     lengths: jnp.ndarray, *, block_k: int | None = None,
-    interpret: bool = False,
+    scale: float | None = None, interpret: bool = False,
 ) -> jnp.ndarray:
     """q: (B, H, D); k/v: (L, B, T, K, D); layer: () or (1,) int32;
-    lengths: (B,) int32 -> (B, H, D)."""
+    lengths: (B,) int32 -> (B, H, D).  Scores are scaled by ``scale``, by
+    default D**-0.5."""
     bsz, h, d = q.shape
     t, n_kv = k.shape[2], k.shape[3]
     block_k = block_k or default_block_k(t, n_kv, d, h, k.dtype.itemsize)
@@ -151,7 +152,8 @@ def decode_attention_stacked(
     def fixed(b, j, *_):
         return 0, 0
 
-    kernel = functools.partial(_decode_kernel, scale=1.0 / math.sqrt(d),
+    scale = 1.0 / math.sqrt(d) if scale is None else scale
+    kernel = functools.partial(_decode_kernel, scale=scale,
                                block_k=block_k, n_kv_heads=n_kv,
                                n_kv_blocks=n_k, ragged=t % block_k != 0)
     kv_spec = pl.BlockSpec((None, None, block_k, n_kv, d), kv_index)

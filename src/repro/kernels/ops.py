@@ -39,13 +39,15 @@ def flash_attention(q, k, v, *, causal=True, window=0, block_q=128,
     return of.reshape(b, h, s, d).transpose(0, 2, 1, 3)
 
 
-@functools.partial(jax.jit, static_argnames=("block_k", "interpret"))
+@functools.partial(jax.jit, static_argnames=("block_k", "scale",
+                                             "interpret"))
 def flash_decode_stacked(q, k_stack, v_stack, layer, lengths, *, block_k=None,
-                         interpret=False):
+                         scale=None, interpret=False):
     """q: (B,1,H,D); k/v stack: (L,B,T,K,D), read at ``layer`` in place;
-    lengths (B,) -> (B,1,H,D)."""
+    lengths (B,) -> (B,1,H,D); scores scaled by ``scale`` (D**-0.5)."""
     out = decode_attention_stacked(q[:, 0], k_stack, v_stack, layer, lengths,
-                                   block_k=block_k, interpret=interpret)
+                                   block_k=block_k, scale=scale,
+                                   interpret=interpret)
     return out[:, None]
 
 

@@ -79,12 +79,10 @@ def cache_shardings(cache_specs, ctx: MeshContext):
         nd = len(leaf.shape)
         if name in ("k", "v", "dk", "dv", "cross_k", "cross_v") and nd == 5:
             spec = P(None, data, "model", None, None)
-        elif name == "ssm":
-            spec = (P(None, data, "model", None, None) if nd == 5
-                    else P(None, None, data, "model", None, None))
-        elif name == "conv":
-            spec = (P(None, data, None, "model") if nd == 4
-                    else P(None, None, data, None, "model"))
+        elif name == "ssm":  # (L, B, H, N, P)
+            spec = P(None, data, "model", None, None)
+        elif name == "conv":  # (L, B, K - 1, C)
+            spec = P(None, data, None, "model")
         else:  # pos and misc scalars
             spec = P()
         return NamedSharding(ctx.mesh, _sanitize(ctx, leaf.shape, spec))

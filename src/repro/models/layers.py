@@ -5,6 +5,7 @@ activation sharding via ``repro.distributed.constrain``.
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Dict, Optional, Tuple
 
@@ -17,6 +18,8 @@ from repro.kernels.ops import flash_decode_stacked
 
 DATA = ("pod", "data")  # batch axes (sanitized away when mesh lacks "pod")
 MODEL = "model"
+# ``ModelConfig.attn_scale`` -> the divisor of d_head under the square root
+SCALE_DIVISOR = {"head": 1, "half_head": 2}
 
 
 # ---------------------------------------------------------------------------
@@ -70,16 +73,17 @@ def _group_query(q: jnp.ndarray, n_kv: int) -> jnp.ndarray:
 def naive_attention(
     q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     causal: bool = True, window: int = 0,
-    q_offset: int = 0, softcap: float = 0.0,
+    q_offset: int = 0, softcap: float = 0.0, scale: Optional[float] = None,
 ) -> jnp.ndarray:
     """Materializes the full (S, T) score matrix — the paper-baseline path.
 
-    q: (B,S,H,D); k/v: (B,T,K,D).  Returns (B,S,H,D).
+    q: (B,S,H,D); k/v: (B,T,K,D).  Returns (B,S,H,D).  Scores are scaled by
+    ``scale``, by default D**-0.5.
     """
     b, s, h, d = q.shape
     t, n_kv = k.shape[1], k.shape[2]
     qg = _group_query(q, n_kv)
-    scale = 1.0 / math.sqrt(d)
+    scale = 1.0 / math.sqrt(d) if scale is None else scale
     # Native-dtype operands with f32 accumulation: never materialize an f32
     # copy of K/V (2x HBM) — MXU accumulates in f32 anyway.
     scores = jnp.einsum("bskgd,btkd->bkgst", qg, k,
@@ -103,7 +107,7 @@ def naive_attention(
 def chunked_attention(
     q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     chunk: int = 512, causal: bool = True, window: int = 0,
-    q_offset: int = 0, softcap: float = 0.0,
+    q_offset: int = 0, softcap: float = 0.0, scale: Optional[float] = None,
 ) -> jnp.ndarray:
     """Online-softmax attention over KV chunks (flash-style in XLA).
 
@@ -114,10 +118,11 @@ def chunked_attention(
     b, s, h, d = q.shape
     t, n_kv = k.shape[1], k.shape[2]
     if t % chunk != 0:
-        return naive_attention(q, k, v, causal, window, q_offset, softcap)
+        return naive_attention(q, k, v, causal, window, q_offset, softcap,
+                               scale)
     n_chunks = t // chunk
     qg = _group_query(q, n_kv)
-    scale = 1.0 / math.sqrt(d)
+    scale = 1.0 / math.sqrt(d) if scale is None else scale
     qpos = (jnp.arange(s) + q_offset)[:, None]  # (S,1)
 
     kc = k.reshape(b, n_chunks, chunk, n_kv, d)
@@ -167,6 +172,7 @@ def chunked_attention(
 def decode_attention(
     q: jnp.ndarray, k_cache: jnp.ndarray, v_cache: jnp.ndarray,
     lengths: jnp.ndarray, window: int = 0, softcap: float = 0.0,
+    scale: Optional[float] = None,
 ) -> jnp.ndarray:
     """Single-position attention against a (B,T,K,D) cache.
 
@@ -176,7 +182,7 @@ def decode_attention(
     b, _, h, d = q.shape
     t, n_kv = k_cache.shape[1], k_cache.shape[2]
     qg = _group_query(q, n_kv)[:, 0].astype(k_cache.dtype)  # (B,K,G,D)
-    scale = 1.0 / math.sqrt(d)
+    scale = 1.0 / math.sqrt(d) if scale is None else scale
     # Cache stays in its storage dtype; f32 accumulation via the MXU.  An
     # .astype(f32) here would materialize a second full-cache-sized buffer.
     scores = jnp.einsum("bkgd,btkd->bkgt", qg, k_cache,
@@ -197,7 +203,7 @@ def decode_attention(
 def stacked_decode_attention(
     q: jnp.ndarray, k_stack: jnp.ndarray, v_stack: jnp.ndarray,
     layer: jnp.ndarray, lengths: jnp.ndarray, window: int = 0,
-    softcap: float = 0.0,
+    softcap: float = 0.0, scale: Optional[float] = None,
 ) -> jnp.ndarray:
     """``decode_attention`` against layer ``layer`` of a stacked (L,B,T,K,D)
     cache.
@@ -212,7 +218,7 @@ def stacked_decode_attention(
         return decode_attention(
             q, jax.lax.dynamic_index_in_dim(k_stack, layer, keepdims=False),
             jax.lax.dynamic_index_in_dim(v_stack, layer, keepdims=False),
-            lengths, window=window, softcap=softcap)
+            lengths, window=window, softcap=softcap, scale=scale)
 
     mesh = current_mesh()
     _, _, h, d = q.shape
@@ -221,7 +227,8 @@ def stacked_decode_attention(
             or (mesh is not None and mesh.mesh.size > 1)):
         return xla(q, k_stack, v_stack, layer, lengths)
     return jax.lax.platform_dependent(
-        q, k_stack, v_stack, layer, lengths, tpu=flash_decode_stacked,
+        q, k_stack, v_stack, layer, lengths,
+        tpu=functools.partial(flash_decode_stacked, scale=scale),
         default=xla)
 
 
@@ -238,7 +245,6 @@ def attention_block(
     positions: jnp.ndarray,
     kv_cache: Optional[Tuple[jnp.ndarray, jnp.ndarray]] = None,
     cache_pos: Optional[jnp.ndarray] = None,
-    cache_fill: Optional[jnp.ndarray] = None,
     cache_layer: Optional[jnp.ndarray] = None,
     causal: bool = True,
     kv_x: Optional[jnp.ndarray] = None,
@@ -249,7 +255,8 @@ def attention_block(
     * prefill/train: ``new_kv`` is this segment's rope'd (K, V) — the caller
       may install it as the cache.
     * decode (``kv_cache`` + scalar ``cache_pos`` and ``cache_layer`` given):
-      ``kv_cache`` is the stacked (L,B,T,K,D) cache of every layer; the new
+      ``kv_cache`` is the stacked (L,B,T,K,Dc) cache of every layer (Dc:
+      ``cfg.cache_head_dim``); the new
       token's K/V is written in place at (``cache_layer``, ``cache_pos``),
       attention reads that layer from the stack, and ``new_kv`` is the
       updated stack.
@@ -258,6 +265,7 @@ def attention_block(
     b, s, _ = x.shape
     h, k_heads, d = cfg.n_heads, cfg.n_kv_heads, cfg.d_head
     kv_src = x if kv_x is None else kv_x
+    scale = 1.0 / math.sqrt(d / SCALE_DIVISOR[cfg.attn_scale])
 
     q = (x @ params["wq"]).reshape(b, s, h, d)
     kk = (kv_src @ params["wk"]).reshape(b, kv_src.shape[1], k_heads, d)
@@ -275,29 +283,34 @@ def attention_block(
 
     if kv_cache is not None:
         k_cache, v_cache = kv_cache
+        # A cache of heads wider than d (``cfg.kv_head_align``): zeros past
+        # d in q, K and V leave the scores, and the output's first d lanes.
+        wide = k_cache.shape[-1] - d
+        if wide:
+            q, kk, vv = (jnp.pad(a, ((0, 0),) * 3 + ((0, wide),))
+                         for a in (q, kk, vv))
         at = (cache_layer, 0, cache_pos, 0, 0)
         k_cache = jax.lax.dynamic_update_slice(
             k_cache, kk[None].astype(k_cache.dtype), at)
         v_cache = jax.lax.dynamic_update_slice(
             v_cache, vv[None].astype(v_cache.dtype), at)
-        fill = cache_fill if cache_fill is not None else cache_pos + s
-        lengths = jnp.full((b,), fill, dtype=jnp.int32)
-        # Ring-buffer caches (windowed attention) index positions modulo the
-        # buffer, so the window re-mask inside decode_attention must be off
-        # (every live slot is in-window by construction).
-        win = 0 if cache_fill is not None else cfg.window
+        lengths = jnp.full((b,), cache_pos + s, dtype=jnp.int32)
         out = stacked_decode_attention(q, k_cache, v_cache, cache_layer,
-                                       lengths, window=win,
-                                       softcap=cfg.attn_logit_softcap)
+                                       lengths, window=cfg.window,
+                                       softcap=cfg.attn_logit_softcap,
+                                       scale=scale)
+        if wide:
+            out = out[..., :d]
         new_kv = (k_cache, v_cache)
     else:
         if run.attention_impl == "naive":
             out = naive_attention(q, kk, vv, causal=causal, window=cfg.window,
-                                  softcap=cfg.attn_logit_softcap)
+                                  softcap=cfg.attn_logit_softcap, scale=scale)
         else:
             out = chunked_attention(q, kk, vv, chunk=run.attention_chunk,
                                     causal=causal, window=cfg.window,
-                                    softcap=cfg.attn_logit_softcap)
+                                    softcap=cfg.attn_logit_softcap,
+                                    scale=scale)
         new_kv = (kk, vv)
     out = constrain(out, DATA, None, MODEL, None)
     y = out.reshape(b, s, h * d) @ params["wo"]
@@ -309,12 +322,22 @@ def attention_block(
 # ---------------------------------------------------------------------------
 
 
-def mlp_block(params: Dict[str, jnp.ndarray], x: jnp.ndarray, act: str) -> jnp.ndarray:
-    if act == "swiglu":
+def mlp_block(params: Dict[str, jnp.ndarray], x: jnp.ndarray, act: str,
+              adapter: Optional[Tuple[jnp.ndarray, jnp.ndarray]] = None
+              ) -> jnp.ndarray:
+    """``swiglu`` and ``geglu`` (exact-erf GELU) gate one half of ``wi``'s
+    output with the other; ``adapter`` (down, up), a low-rank map added to
+    that gate_up projection."""
+    if act in ("swiglu", "geglu"):
         gate_up = x @ params["wi"]  # (..., 2*ff)
+        if adapter is not None:
+            gate_up = gate_up + (x @ adapter[0]) @ adapter[1]
         gate_up = constrain(gate_up, DATA, None, MODEL)
         gate, up = jnp.split(gate_up, 2, axis=-1)
-        hidden = jax.nn.silu(gate) * up
+        if act == "swiglu":
+            hidden = jax.nn.silu(gate) * up
+        else:
+            hidden = jax.nn.gelu(gate, approximate=False) * up
     else:
         hidden = jax.nn.gelu(x @ params["wi"])
         hidden = constrain(hidden, DATA, None, MODEL)

@@ -6,6 +6,12 @@ recurrence — the loop-carried dependency the HLO LCD analysis surfaces.
 Decode is the O(1)-state recurrence.  The intra-chunk computation has a
 Pallas kernel counterpart (`repro.kernels.ssd_scan`) validated against this
 reference.
+
+B and C come in ``cfg.ssm_groups`` groups (Zamba2-7B: 2), head h reading
+group h // (heads / groups), and the gated RMSNorm runs over each group's
+channels; the depthwise convolution has a bias where ``cfg.ssm_conv_bias``.
+With one group every path computes, bit for bit, what it did before
+groups existed.
 """
 
 from __future__ import annotations
@@ -21,12 +27,13 @@ from repro.models.layers import DATA, MODEL, rms_norm
 
 def init_mamba_params(key, cfg, layer_count, dtype) -> Dict[str, jnp.ndarray]:
     """Stacked Mamba-2 block params with leading ``layer_count`` dims."""
-    d, di, n, nh = cfg.d_model, cfg.d_inner, cfg.ssm_state, cfg.ssm_heads
-    conv_ch = di + 2 * n
+    d, di, nh = cfg.d_model, cfg.d_inner, cfg.ssm_heads
+    gn = cfg.ssm_groups * cfg.ssm_state  # B (and C) of every group
+    conv_ch = di + 2 * gn
     keys = jax.random.split(key, 6)
     scale = 0.02
-    proj_out = 2 * di + 2 * n + nh  # z, x, B, C, dt
-    return {
+    proj_out = 2 * di + 2 * gn + nh  # z, x, B, C, dt
+    params = {
         "in_proj": jax.random.normal(keys[0], (*layer_count, d, proj_out), dtype) * scale,
         "conv_w": jax.random.normal(keys[1], (*layer_count, cfg.ssm_conv, conv_ch), dtype) * scale,
         "A_log": jnp.zeros((*layer_count, nh), dtype),
@@ -35,11 +42,17 @@ def init_mamba_params(key, cfg, layer_count, dtype) -> Dict[str, jnp.ndarray]:
         "ssm_norm": jnp.ones((*layer_count, di), dtype),
         "out_proj": jax.random.normal(keys[2], (*layer_count, di, d), dtype) * scale,
     }
+    if cfg.ssm_conv_bias:
+        params["conv_b"] = jnp.zeros((*layer_count, conv_ch), dtype)
+    return params
 
 
 def _causal_conv(x: jnp.ndarray, w: jnp.ndarray,
-                 state: Optional[jnp.ndarray] = None) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Depthwise causal conv1d via shifted adds.  x: (B,S,C); w: (K,C).
+                 state: Optional[jnp.ndarray] = None,
+                 bias: Optional[jnp.ndarray] = None
+                 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Depthwise causal conv1d via shifted adds, then SiLU.  x: (B,S,C);
+    w: (K,C); bias: (C,) or None.
 
     ``state``: (B, K-1, C) trailing context from the previous segment.
     Returns (y, new_state)."""
@@ -51,16 +64,51 @@ def _causal_conv(x: jnp.ndarray, w: jnp.ndarray,
     y = jnp.zeros_like(x)
     for i in range(k):
         y = y + xp[:, i:i + s, :] * w[i]
+    if bias is not None:
+        y = y + bias
     new_state = xp[:, -(k - 1):, :] if k > 1 else state
     return jax.nn.silu(y), new_state
 
 
 def _split_proj(proj: jnp.ndarray, cfg):
-    di, n, nh = cfg.d_inner, cfg.ssm_state, cfg.ssm_heads
+    di, nh = cfg.d_inner, cfg.ssm_heads
+    gn = cfg.ssm_groups * cfg.ssm_state
     z = proj[..., :di]
-    xbc = proj[..., di:di + di + 2 * n]
+    xbc = proj[..., di:di + di + 2 * gn]
     dt = proj[..., -nh:]
     return z, xbc, dt
+
+
+def _per_head(v: jnp.ndarray, groups: int, heads: int) -> jnp.ndarray:
+    """(..., G*N) of G groups -> (..., H, N): head h reads group
+    h // (H / G)."""
+    v = v.reshape(*v.shape[:-1], groups, v.shape[-1] // groups)
+    return jnp.repeat(v, heads // groups, axis=-2)
+
+
+def ssd_grouped(x, dt, A, Bm, Cm, chunk, groups, h0=None,
+                chunk_shard=False):
+    """``ssd_chunked`` where B and C come in ``groups`` groups, Bm/Cm:
+    (B,S,G*N): the heads, in G equal runs, each read their own group's
+    (the scan mapped over the groups; with one group, ``ssd_chunked``
+    itself: mapped over one group, the train step's gradients round
+    differently)."""
+    if groups == 1:
+        return ssd_chunked(x, dt, A, Bm, Cm, chunk, h0,
+                           chunk_shard=chunk_shard)
+    b, s, nh, p = x.shape
+
+    def split(v, axis):  # the head (or B/C) axis -> (G, per group)
+        return v.reshape(*v.shape[:axis], groups, -1, *v.shape[axis + 1:])
+
+    y, h = jax.vmap(
+        lambda x, dt, a, bm, cm, h0: ssd_chunked(
+            x, dt, a, bm, cm, chunk, h0, chunk_shard=chunk_shard),
+        in_axes=(2, 2, 0, 2, 2, None if h0 is None else 1),
+        out_axes=(2, 1))(
+            split(x, 2), split(dt, 2), split(A, 0), split(Bm, 2),
+            split(Cm, 2), None if h0 is None else split(h0, 1))
+    return y.reshape(b, s, nh, p), h.reshape(b, nh, *h.shape[3:])
 
 
 def ssd_chunked(
@@ -199,6 +247,8 @@ def mamba_block(
     """
     b, s, _ = x.shape
     di, n, nh, p = cfg.d_inner, cfg.ssm_state, cfg.ssm_heads, cfg.ssm_head_dim
+    groups = cfg.ssm_groups
+    gn = groups * n
 
     proj = x @ params["in_proj"]
     if chunk_shard and not single_step:
@@ -206,10 +256,11 @@ def mamba_block(
     else:
         proj = constrain(proj, DATA, None, MODEL)
     z, xbc, dt_raw = _split_proj(proj, cfg)
-    xbc, conv_state = _causal_conv(xbc, params["conv_w"], conv_state)
+    xbc, conv_state = _causal_conv(xbc, params["conv_w"], conv_state,
+                                   params.get("conv_b"))
     xs = xbc[..., :di].reshape(b, s, nh, p)
-    Bm = xbc[..., di:di + n]
-    Cm = xbc[..., di + n:]
+    Bm = xbc[..., di:di + gn]
+    Cm = xbc[..., di + gn:]
     dt = jax.nn.softplus(dt_raw.astype(jnp.float32)
                          + params["dt_bias"].astype(jnp.float32))
     A = -jnp.exp(params["A_log"].astype(jnp.float32))
@@ -219,17 +270,22 @@ def mamba_block(
         h_prev = (jnp.zeros((b, nh, n, p), jnp.float32) if ssm_state is None
                   else ssm_state.astype(jnp.float32))
         xdt = xs[:, 0].astype(jnp.float32) * dt[:, 0][..., None]  # (B,H,P)
-        h_new = dA[..., None, None] * h_prev + jnp.einsum(
-            "bn,bhp->bhnp", Bm[:, 0].astype(jnp.float32), xdt)
-        y = jnp.einsum("bn,bhnp->bhp", Cm[:, 0].astype(jnp.float32), h_new)
+        bm, cm = Bm[:, 0].astype(jnp.float32), Cm[:, 0].astype(jnp.float32)
+        bm, cm = _per_head(bm, groups, nh), _per_head(cm, groups, nh)
+        h_new = dA[..., None, None] * h_prev \
+            + bm[..., None] * xdt[:, :, None, :]
+        y = jnp.einsum("bhn,bhnp->bhp", cm, h_new)
         y = y[:, None].astype(x.dtype)  # (B,1,H,P)
         ssm_state = h_new.astype(x.dtype)
     else:
-        y, ssm_state = ssd_chunked(xs, dt, A, Bm, Cm, cfg.ssm_chunk, ssm_state,
-                                   chunk_shard=chunk_shard)
+        y, ssm_state = ssd_grouped(xs, dt, A, Bm, Cm, cfg.ssm_chunk, groups,
+                                   ssm_state, chunk_shard=chunk_shard)
 
     y = y + params["D"].astype(x.dtype)[None, None, :, None] * xs
     y = y.reshape(b, s, di)
-    y = rms_norm(y * jax.nn.silu(z), params["ssm_norm"], cfg.norm_eps)
+    # the gated norm runs over each group's d_inner / G channels
+    y = rms_norm((y * jax.nn.silu(z)).reshape(b, s, groups, -1),
+                 params["ssm_norm"].reshape(groups, -1),
+                 cfg.norm_eps).reshape(b, s, di)
     out = y @ params["out_proj"]
     return constrain(out, DATA, None, None), ssm_state, conv_state

@@ -8,8 +8,9 @@ the scan body.  Families:
   moe     — dense attention + GShard MoE FFN (deepseek-moe, phi3.5-moe),
             optional leading dense-FFN layers (DeepSeek layer 0)
   ssm     — Mamba-2 SSD stack (mamba2-130m)
-  hybrid  — Mamba-2 backbone + one shared attention block every k layers
-            (zamba2), concat(x, embed0) input per Zamba design
+  hybrid  — Zamba2: a Mamba-2 stack with shared attention blocks, used in
+            turn, before the layers ``hybrid_pattern`` marks; each block
+            reads concat(x, embed0) and feeds that Mamba-2 layer's input
   audio   — Whisper-style encoder/decoder backbone, stub frame embeddings
   vlm     — dense backbone with stub patch embeddings prepended (phi3-vision)
 """
@@ -62,7 +63,7 @@ def _init_attn(key, cfg, layer_count, dtype, d_in=None) -> Params:
 def _init_mlp(key, cfg, layer_count, dtype, d_ff=None, d_in=None) -> Params:
     d = d_in or cfg.d_model
     ff = d_ff or cfg.d_ff
-    width = 2 * ff if cfg.act == "swiglu" else ff
+    width = 2 * ff if cfg.act in ("swiglu", "geglu") else ff
     k1, k2 = jax.random.split(key)
     s = 0.02
     return {
@@ -113,19 +114,27 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
             "norm1": jnp.ones((cfg.n_layers, cfg.d_model), dtype),
         }
     elif fam == "hybrid":
-        every = cfg.hybrid_attn_every
-        n_groups = cfg.n_layers // every
+        d, nb = cfg.d_model, cfg.hybrid_blocks
+        n_inv, r = len(cfg.hybrid_ids), cfg.adapter_rank
         params["layers"] = {
-            "mamba": init_mamba_params(keys[2], cfg, (n_groups, every), dtype),
-            "norm1": jnp.ones((n_groups, every, cfg.d_model), dtype),
+            "mamba": init_mamba_params(keys[2], cfg, (cfg.n_layers,), dtype),
+            "norm1": jnp.ones((cfg.n_layers, d), dtype),
         }
-        d2 = 2 * cfg.d_model
-        params["shared_attn"] = _init_attn(keys[3], cfg, (), dtype, d_in=d2)
-        params["shared_mlp"] = _init_mlp(keys[4], cfg, (), dtype, d_in=d2)
-        params["shared_norm1"] = jnp.ones((d2,), dtype)
-        params["shared_norm2"] = jnp.ones((d2,), dtype)
-        params["inv_proj"] = jax.random.normal(
-            keys[5], (n_groups, cfg.d_model, cfg.d_model), dtype) * 0.02
+        params["shared"] = {
+            "attn": _init_attn(keys[3], cfg, (nb,), dtype, d_in=2 * d),
+            "mlp": _init_mlp(keys[4], cfg, (nb,), dtype),
+            "norm1": jnp.ones((nb, 2 * d), dtype),
+            "norm2": jnp.ones((nb, d), dtype),
+        }
+        ks = jax.random.split(keys[5], 3)
+        inv = {"linear": jax.random.normal(ks[0], (n_inv, d, d), dtype) * 0.02}
+        if r:
+            width = params["shared"]["mlp"]["wi"].shape[-1]
+            inv["adapter_down"] = jax.random.normal(
+                ks[1], (n_inv, d, r), dtype) * 0.02
+            inv["adapter_up"] = jax.random.normal(
+                ks[2], (n_inv, r, width), dtype) * 0.02
+        params["invocations"] = inv
     elif fam == "audio":
         params["enc_layers"] = _init_dense_block(
             keys[2], cfg, (cfg.n_encoder_layers,), dtype)
@@ -192,19 +201,122 @@ def moe_layer_block(lp, x, cfg, run, positions, kv_cache=None, cache_pos=None,
     return _seq_constrain(x + h, run), kv, aux
 
 
-def hybrid_shared_block(params, x, x0, inv_proj, cfg, run, positions,
-                        kv_cache=None, cache_pos=None, cache_fill=None,
-                        cache_layer=None):
-    """Zamba2 shared attention block on concat(x, embed0)."""
-    xin = jnp.concatenate([x, x0], axis=-1)
-    h, kv = attention_block(params["shared_attn"],
-                            rms_norm(xin, params["shared_norm1"], cfg.norm_eps),
-                            cfg, run, positions, kv_cache=kv_cache,
-                            cache_pos=cache_pos, cache_fill=cache_fill,
-                            cache_layer=cache_layer)
-    m = mlp_block(params["shared_mlp"],
-                  rms_norm(xin, params["shared_norm2"], cfg.norm_eps), cfg.act)
-    return _seq_constrain(x + (h + m) @ inv_proj, run), kv
+def hybrid_shared_block(params, x, x0, inv: int, cfg, run, positions,
+                        kv_cache=None, cache_pos=None):
+    """Zamba2's shared block at invocation ``inv``: block ``inv %
+    hybrid_blocks`` on concat(x, embed0), attention then the MLP with the
+    invocation's adapter (no residual inside), then the invocation's linear
+    map.  Returns what is added to the next Mamba-2 layer's input, and the
+    KV (decode: the stack, written at layer ``inv``)."""
+    blk = jax.tree.map(lambda a: a[inv % cfg.hybrid_blocks], params["shared"])
+    own = jax.tree.map(lambda a: a[inv], params["invocations"])
+    h = rms_norm(jnp.concatenate([x, x0], axis=-1), blk["norm1"],
+                 cfg.norm_eps)
+    h, kv = attention_block(blk["attn"], h, cfg, run, positions,
+                            kv_cache=kv_cache, cache_pos=cache_pos,
+                            cache_layer=jnp.asarray(inv, jnp.int32))
+    adapter = (own["adapter_down"], own["adapter_up"]) \
+        if "adapter_down" in own else None
+    h = mlp_block(blk["mlp"], rms_norm(h, blk["norm2"], cfg.norm_eps),
+                  cfg.act, adapter)
+    return h @ own["linear"], kv
+
+
+def hybrid_runs(cfg) -> list:
+    """``(invocation or None, first layer, end layer)`` of each run of
+    Mamba-2 layers: runs start at layer 0 and at each shared block's layer,
+    whose invocation feeds the run's first layer."""
+    ids = cfg.hybrid_ids
+    starts = sorted({0, *ids})
+    return [(ids.index(s) if s in ids else None, s, e)
+            for s, e in zip(starts, starts[1:] + [cfg.n_layers])]
+
+
+def mamba_run(layers, x, inject, first, end, cfg, run, states=None,
+              collect=False):
+    """Mamba-2 layers ``first..end-1`` of the stack ``layers`` (indexed in
+    place, not sliced out), pre-norm, residual; ``inject`` (or None) is
+    added to the first layer's input only.
+
+    ``states``: decode's (ssm, conv) stacks of every layer, read and
+    written in place at each layer's index; returns (x, states).  Else
+    (prefill/train) returns (x, each layer's (ssm, conv) stacked if
+    ``collect``)."""
+    def at(a, i):
+        return jax.lax.dynamic_index_in_dim(a, i, keepdims=False)
+
+    def body(carry, i):
+        x, inj, st = carry
+        lp = jax.tree.map(lambda a: at(a, i), layers)
+        h = rms_norm(x if inj is None else x + inj, lp["norm1"], cfg.norm_eps)
+        if st is None:
+            y, ssm, conv = mamba_block(lp["mamba"], h, cfg,
+                                       chunk_shard=run.ssd_chunk_shard)
+            out = (ssm, conv) if collect else 0
+        else:
+            y, ssm, conv = mamba_block(
+                lp["mamba"], h, cfg, ssm_state=at(st[0], i),
+                conv_state=at(st[1], i), single_step=True)
+            st = tuple(jax.lax.dynamic_update_index_in_dim(s, new, i, 0)
+                       for s, new in zip(st, (ssm, conv)))
+            out = 0
+        inj = None if inj is None else jnp.zeros_like(inj)
+        return (_seq_constrain(x + y, run), inj, st), out
+
+    if states is None and run.remat != "none":
+        body = jax.checkpoint(body)  # per-layer SSD remat
+    (x, _, states), ys = jax.lax.scan(
+        body, (x, inject, states), jnp.arange(first, end))
+    return x, (states if states is not None else ys)
+
+
+def hybrid_stack(params, x, cfg, run, positions, cache=None, collect=False):
+    """The hybrid family's layers over embeddings ``x``, run by run
+    (``hybrid_runs``), each shared block under the named scope
+    ``shared_block`` and each run of Mamba-2 layers under ``mamba``.
+
+    Prefill/train (``cache`` None): returns (x, extras) with, if
+    ``collect``, ``kv`` (K, V stacked by invocation) and ``ssm`` (ssm and
+    conv states stacked by layer).  Decode: ``cache`` holds those stacks
+    and the position; returns (x, their updates)."""
+    x0, kvs, states, decode = x, [], [], cache is not None
+    if decode:
+        k, v, st = cache["k"], cache["v"], (cache["ssm"], cache["conv"])
+    for inv, first, end in hybrid_runs(cfg):
+        inject = None
+        if inv is not None:
+            with jax.named_scope("shared_block"):
+                if decode:
+                    inject, (k, v) = hybrid_shared_block(
+                        params, x, x0, inv, cfg, run, positions,
+                        kv_cache=(k, v), cache_pos=cache["pos"])
+                else:
+                    inject, kv = hybrid_shared_block(params, x, x0, inv, cfg,
+                                                     run, positions)
+                    kvs.append(kv)
+        with jax.named_scope("mamba"):
+            x, out = mamba_run(params["layers"], x, inject, first, end, cfg,
+                               run, st if decode else None, collect)
+        if decode:
+            st = out
+        elif collect:
+            states.append(out)
+    if decode:
+        return x, {"k": k, "v": v, "ssm": st[0], "conv": st[1]}
+    extras = {}
+    if collect:
+        extras["kv"] = tuple(jnp.stack(t) for t in zip(*kvs))
+        extras["ssm"] = tuple(jnp.concatenate(t) for t in zip(*states))
+    return x, extras
+
+
+def _cache_heads(kv, stack):
+    """(..., K, D) K or V in a cache stack's dtype, zeros past D up to the
+    stack's head width (``kv_head_align``)."""
+    wide = stack.shape[-1] - kv.shape[-1]
+    if wide:
+        kv = jnp.pad(kv, ((0, 0),) * (kv.ndim - 1) + ((0, wide),))
+    return kv.astype(stack.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -305,33 +417,9 @@ def forward_hidden(
             extras["ssm"] = states
 
     elif fam == "hybrid":
-        x0 = x
-        n_groups = cfg.n_layers // cfg.hybrid_attn_every
-
-        def group_body(xg, lp):
-            def inner(c, lpi):
-                h = rms_norm(c, lpi["norm1"], cfg.norm_eps)
-                y, ssm, conv = mamba_block(lpi["mamba"], h, cfg,
-                                           chunk_shard=run.ssd_chunk_shard)
-                return _seq_constrain(c + y, run), ((ssm, conv) if collect_kv else 0)
-
-            if run.remat != "none":
-                inner = jax.checkpoint(inner)  # nested: per-layer SSD remat
-            xg, states = jax.lax.scan(
-                inner, xg,
-                {"mamba": lp["mamba"], "norm1": lp["norm1"]})
-            xg, kv = hybrid_shared_block(params, xg, x0, lp["inv_proj"],
-                                         cfg, run, positions)
-            out = (states, kv) if collect_kv else 0
-            return xg, out
-
-        stacked = {"mamba": params["layers"]["mamba"],
-                   "norm1": params["layers"]["norm1"],
-                   "inv_proj": params["inv_proj"]}
-        wrapped = _remat(group_body, run)
-        x, ys = jax.lax.scan(wrapped, x, stacked)
-        if collect_kv:
-            extras["ssm"], extras["kv"] = ys
+        x, states = hybrid_stack(params, x, cfg, run, positions,
+                                 collect=collect_kv)
+        extras.update(states)
 
     elif fam == "audio":
         # Encoder over stub frame embeddings.
@@ -378,11 +466,11 @@ def forward_train(params, cfg, run, tokens, frontend=None):
 def init_cache(cfg: ModelConfig, batch: int, max_len: int) -> Params:
     """Abstract-friendly cache pytree (zeros; dryrun passes ShapeDtypeStructs)."""
     dtype = _dtype(cfg)
-    hkv, dh = cfg.n_kv_heads, cfg.d_head
+    hkv = cfg.n_kv_heads
     cache: Params = {"pos": jnp.zeros((), jnp.int32)}
     fam = cfg.family
 
-    def kv(layer_count, length):
+    def kv(layer_count, length, dh=cfg.cache_head_dim):
         return (jnp.zeros((layer_count, batch, length, hkv, dh), dtype),
                 jnp.zeros((layer_count, batch, length, hkv, dh), dtype))
 
@@ -393,46 +481,59 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int) -> Params:
         cache["k"], cache["v"] = kv(n_moe, max_len)
         if cfg.moe_first_dense:
             cache["dk"], cache["dv"] = kv(cfg.moe_first_dense, max_len)
-    elif fam == "ssm":
-        di, n, nh, p = cfg.d_inner, cfg.ssm_state, cfg.ssm_heads, cfg.ssm_head_dim
-        conv_ch = di + 2 * n
+    elif fam in ("ssm", "hybrid"):
+        nh, n, p = cfg.ssm_heads, cfg.ssm_state, cfg.ssm_head_dim
+        conv_ch = cfg.d_inner + 2 * cfg.ssm_groups * n
         cache["ssm"] = jnp.zeros((cfg.n_layers, batch, nh, n, p), dtype)
-        cache["conv"] = jnp.zeros((cfg.n_layers, batch, cfg.ssm_conv - 1, conv_ch), dtype)
-    elif fam == "hybrid":
-        every = cfg.hybrid_attn_every
-        n_groups = cfg.n_layers // every
-        di, n, nh, p = cfg.d_inner, cfg.ssm_state, cfg.ssm_heads, cfg.ssm_head_dim
-        conv_ch = di + 2 * n
-        cache["ssm"] = jnp.zeros((n_groups, every, batch, nh, n, p), dtype)
-        cache["conv"] = jnp.zeros((n_groups, every, batch, cfg.ssm_conv - 1, conv_ch), dtype)
-        wlen = min(cfg.window or max_len, max_len)
-        cache["k"], cache["v"] = kv(n_groups, wlen)
+        cache["conv"] = jnp.zeros(
+            (cfg.n_layers, batch, cfg.ssm_conv - 1, conv_ch), dtype)
+        if fam == "hybrid":  # by invocation
+            cache["k"], cache["v"] = kv(len(cfg.hybrid_ids), max_len)
     elif fam == "audio":
         cache["k"], cache["v"] = kv(cfg.n_layers, max_len)
         f = cfg.frontend_len
-        cache["cross_k"], cache["cross_v"] = kv(cfg.n_layers, f)
+        cache["cross_k"], cache["cross_v"] = kv(cfg.n_layers, f, cfg.d_head)
     return cache
 
 
 def prefill(params, cfg, run, tokens, frontend=None):
-    """Full-sequence forward that also returns the populated KV caches."""
+    """Full-sequence forward that also returns the populated KV caches.
+    With ``run.prefill_rows`` (and no frontend), the rows go through it a
+    block at a time, each block's logits and caches written into the
+    whole wave's."""
+    b, rows = tokens.shape[0], run.prefill_rows
+    if not rows or rows >= b or frontend is not None:
+        return _prefill(params, cfg, run, tokens, frontend)
+    assert b % rows == 0, f"{b} rows do not split into blocks of {rows}"
+
+    def block(i, done):
+        logits, cache = done
+        start = i * rows
+        part_logits, part = _prefill(
+            params, cfg, run, jax.lax.dynamic_slice_in_dim(tokens, start, rows))
+        logits = jax.lax.dynamic_update_slice_in_dim(logits, part_logits,
+                                                     start, axis=0)
+        return logits, {  # every cache leaf but the position is (L, B, ...)
+            key: a if key == "pos" else jax.lax.dynamic_update_slice_in_dim(
+                a, part[key], start, axis=1)
+            for key, a in cache.items()}
+
+    cache = init_cache(cfg, b, tokens.shape[1])
+    cache["pos"] = jnp.asarray(tokens.shape[1], jnp.int32)
+    logits = jnp.zeros((b, 1, cfg.padded_vocab), jnp.float32)
+    return jax.lax.fori_loop(0, b // rows, block, (logits, cache))
+
+
+def _prefill(params, cfg, run, tokens, frontend=None):
     hidden, extras = forward_hidden(params, cfg, run, tokens, frontend,
                                     collect_kv=True)
     logits_last = lm_logits(params, cfg, hidden[:, -1:])
     b = tokens.shape[0]
     s = hidden.shape[1]
     cache = init_cache(cfg, b, s)
-    if "kv" in extras:
-        k, v = extras["kv"]  # (L, B, S, K, D)
-        if cfg.family == "hybrid":
-            w = cache["k"].shape[2]
-            k, v = k[:, :, -w:], v[:, :, -w:]
-        cache["k"] = k.astype(cache["k"].dtype)
-        cache["v"] = v.astype(cache["v"].dtype)
-    if "dense_kv" in extras:
-        dk, dv = extras["dense_kv"]
-        cache["dk"] = dk.astype(cache["dk"].dtype)
-        cache["dv"] = dv.astype(cache["dv"].dtype)
+    for src, names in (("kv", ("k", "v")), ("dense_kv", ("dk", "dv"))):
+        for name, c in zip(names, extras.get(src, ())):  # (L, B, S, K, D)
+            cache[name] = _cache_heads(c, cache[name])
     if "ssm" in extras:
         ssm, conv = extras["ssm"]
         cache["ssm"] = ssm.astype(cache["ssm"].dtype)
@@ -540,37 +641,8 @@ def decode_step(params, cfg, run, cache, tokens):
         new_cache["ssm"], new_cache["conv"] = ssm, conv
 
     elif fam == "hybrid":
-        x0 = x
-        wlen = cache["k"].shape[2]
-        slot = jnp.mod(pos, wlen)
-
-        def group_body(xg, inputs, group, k, v):
-            lp, ssm_g, conv_g = inputs
-
-            def inner(c, xs_inner):
-                lpi, ssm, conv = xs_inner
-                h = rms_norm(c, lpi["norm1"], cfg.norm_eps)
-                y, ssm2, conv2 = mamba_block(lpi["mamba"], h, cfg,
-                                             ssm_state=ssm, conv_state=conv,
-                                             single_step=True)
-                return c + y, (ssm2, conv2)
-
-            xg, states = jax.lax.scan(
-                inner, xg,
-                ({"mamba": lp["mamba"], "norm1": lp["norm1"]}, ssm_g, conv_g))
-            xg, kv = hybrid_shared_block(
-                params, xg, x0, lp["inv_proj"], cfg, run, positions,
-                kv_cache=(k, v), cache_pos=slot,
-                cache_fill=jnp.minimum(pos + 1, wlen), cache_layer=group)
-            return xg, kv, states
-
-        stacked = ({"mamba": params["layers"]["mamba"],
-                    "norm1": params["layers"]["norm1"],
-                    "inv_proj": params["inv_proj"]},
-                   cache["ssm"], cache["conv"])
-        x, k, v, (ssm, conv) = _scan_with_cache(
-            group_body, x, stacked, cache["k"], cache["v"])
-        new_cache.update({"ssm": ssm, "conv": conv, "k": k, "v": v})
+        x, states = hybrid_stack(params, x, cfg, run, positions, cache=cache)
+        new_cache.update(states)
 
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = lm_logits(params, cfg, x)

@@ -28,7 +28,7 @@ import numpy as np
 from jax.profiler import TraceAnnotation
 
 from repro.configs.base import ModelConfig, RunConfig
-from repro.models import decode_step, init_cache, prefill
+from repro.models import decode_step, prefill
 
 
 @dataclasses.dataclass
@@ -53,9 +53,12 @@ class ServeEngine:
         self.max_len = max_len
         # (params, cfg, run, tokens (B, S)) -> (logits (B, 1, V), cache)
         self.prefill = jax.jit(prefill, static_argnums=(1, 2))
-        # (params, cache, tokens (B, 1)) -> (logits (B, 1, V), cache)
+        # (params, cache, tokens (B, 1)) -> (logits (B, 1, V), cache); the
+        # cache is donated, so the step writes it in place and the cache
+        # it was given is gone
         self.decode = jax.jit(
-            lambda p, c, t: decode_step(p, cfg, self.run, c, t))
+            lambda p, c, t: decode_step(p, cfg, self.run, c, t),
+            donate_argnums=(1,))
         self._analysis = None
         self._waves = 0  # waves served so far: the next wave's id
 
@@ -104,8 +107,7 @@ class ServeEngine:
                 self.params, self.cfg, self.run,
                 jnp.asarray(np.asarray(prompts, np.int32)), frontend=frontend)
         with TraceAnnotation("serve.grow_cache", wave=wave):
-            cache = self._grow_cache(cache, lengths[0] + max_new_tokens,
-                                     len(prompts))
+            cache = self._grow_cache(cache, lengths[0] + max_new_tokens)
         return logits, cache
 
     def _run_wave(self, wave, max_new_tokens, eos_id, frontend, return_logits):
@@ -142,20 +144,18 @@ class ServeEngine:
                             if return_logits else None))
                 for i, (rid, p) in enumerate(wave)]
 
-    def _grow_cache(self, cache: Dict, new_len: int, batch: int) -> Dict:
-        grown = init_cache(self.cfg, batch, new_len)
-        for key in ("k", "v", "dk", "dv"):
-            if key in cache and key in grown and \
-                    grown[key].shape[2] > cache[key].shape[2]:
-                pad = grown[key].shape[2] - cache[key].shape[2]
-                grown[key] = jnp.concatenate(
-                    [cache[key],
-                     jnp.zeros((*cache[key].shape[:2], pad, *cache[key].shape[3:]),
-                               cache[key].dtype)], axis=2)
-            elif key in cache:
-                grown[key] = cache[key]
-        for key in ("ssm", "conv", "cross_k", "cross_v"):
-            if key in cache:
-                grown[key] = cache[key]
-        grown["pos"] = cache["pos"]
+    @staticmethod
+    def _grow_cache(cache: Dict, new_len: int) -> Dict:
+        """The prefill's cache with room for ``new_len`` positions: each KV
+        stack (L, B, T, K, D) shorter than that padded with zeros along T.
+        Every leaf is taken out of ``cache`` as it goes, so that only one
+        stack is held twice at a time."""
+        grown = {}
+        for key in list(cache):
+            leaf = cache.pop(key)
+            if key in ("k", "v", "dk", "dv") and leaf.shape[2] < new_len:
+                leaf = jnp.pad(leaf, ((0, 0),) * 2
+                               + ((0, new_len - leaf.shape[2]),)
+                               + ((0, 0),) * 2)
+            grown[key] = leaf
         return grown

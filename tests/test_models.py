@@ -70,7 +70,7 @@ def test_decode_matches_prefill_logits(arch_setup):
     # Decode caches are sized by prefill length; grow for one extra token.
     from repro.serving.engine import ServeEngine
     engine = ServeEngine(cfg, params, run=RUN, batch_size=B)
-    cache = engine._grow_cache(cache, tokens.shape[1] + 4, B)
+    cache = engine._grow_cache(cache, tokens.shape[1] + 4)
     step_logits, cache2 = decode_step(params, cfg, RUN, cache, tokens[:, -1:])
 
     a = np.asarray(full_logits[:, -1], np.float32)
@@ -94,8 +94,7 @@ def _xs_decode_step(params, cfg, run, cache, tokens):
                                      mlp_block, rms_norm, sinusoidal_positions)
     from repro.models.mamba2 import mamba_block
     from repro.models.transformer import (dense_block, embed_tokens,
-                                          hybrid_shared_block, lm_logits,
-                                          moe_layer_block)
+                                          lm_logits, moe_layer_block)
 
     def one(kl, vl):
         return kl[None], vl[None]
@@ -155,33 +154,48 @@ def _xs_decode_step(params, cfg, run, cache, tokens):
         x, (new["k"], new["v"]) = jax.lax.scan(body, x, xs)
     else:
         assert fam == "hybrid", fam
-        x0, wlen = x, cache["k"].shape[2]
+        x0 = x
 
-        def group_body(xg, inputs):
-            lp, ssm_g, conv_g, kl, vl = inputs
+        def mamba_body(carry, inputs):
+            (c, inj), (lp, ssm, conv) = carry, inputs
+            y, ssm2, conv2 = mamba_block(
+                lp["mamba"], rms_norm(c + inj, lp["norm1"], cfg.norm_eps),
+                cfg, ssm_state=ssm, conv_state=conv, single_step=True)
+            return (c + y, jnp.zeros_like(inj)), (ssm2, conv2)
 
-            def inner(c, xs_inner):
-                lpi, ssm, conv = xs_inner
-                y, ssm2, conv2 = mamba_block(
-                    lpi["mamba"], rms_norm(c, lpi["norm1"], cfg.norm_eps), cfg,
-                    ssm_state=ssm, conv_state=conv, single_step=True)
-                return c + y, (ssm2, conv2)
-
-            xg, states = jax.lax.scan(
-                inner, xg,
-                ({"mamba": lp["mamba"], "norm1": lp["norm1"]}, ssm_g, conv_g))
-            xg, kv = hybrid_shared_block(
-                params, xg, x0, lp["inv_proj"], cfg, run, positions,
-                kv_cache=one(kl, vl), cache_pos=jnp.mod(pos, wlen),
-                cache_fill=jnp.minimum(pos + 1, wlen), cache_layer=0)
-            return xg, (states, unstack(kv))
-
-        stacked = ({"mamba": params["layers"]["mamba"],
-                    "norm1": params["layers"]["norm1"],
-                    "inv_proj": params["inv_proj"]},
-                   cache["ssm"], cache["conv"], cache["k"], cache["v"])
-        x, ((new["ssm"], new["conv"]), (new["k"], new["v"])) = jax.lax.scan(
-            group_body, x, stacked)
+        ids = cfg.hybrid_ids
+        starts = sorted({0, *ids})
+        ssms, convs, ks, vs = [], [], [], []
+        for first, end in zip(starts, starts[1:] + [cfg.n_layers]):
+            inj = jnp.zeros_like(x)
+            if first in ids:
+                j = ids.index(first)
+                blk = jax.tree.map(lambda a: a[j % cfg.hybrid_blocks],
+                                   params["shared"])
+                own = jax.tree.map(lambda a: a[j], params["invocations"])
+                h, kv = attention_block(
+                    blk["attn"], rms_norm(jnp.concatenate([x, x0], -1),
+                                          blk["norm1"], cfg.norm_eps),
+                    cfg, run, positions,
+                    kv_cache=one(cache["k"][j], cache["v"][j]), **at)
+                h = mlp_block(blk["mlp"], rms_norm(h, blk["norm2"],
+                                                   cfg.norm_eps), cfg.act,
+                              (own["adapter_down"], own["adapter_up"]))
+                inj = h @ own["linear"]
+                k, v = unstack(kv)
+                ks.append(k)
+                vs.append(v)
+            run_params = jax.tree.map(lambda a: a[first:end],
+                                      params["layers"])
+            (x, _), (s, c) = jax.lax.scan(
+                mamba_body, (x, inj),
+                (run_params, cache["ssm"][first:end],
+                 cache["conv"][first:end]))
+            ssms.append(s)
+            convs.append(c)
+        new["ssm"], new["conv"] = (jnp.concatenate(ssms),
+                                   jnp.concatenate(convs))
+        new["k"], new["v"] = jnp.stack(ks), jnp.stack(vs)
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     new["pos"] = pos + 1
     return lm_logits(params, cfg, x), new
@@ -190,13 +204,14 @@ def _xs_decode_step(params, cfg, run, cache, tokens):
 @pytest.mark.parametrize("arch,changes", [
     ("tinyllama-1.1b", {}),
     ("deepseek-moe-16b", {"n_layers": 3}),  # 1 dense + 2 MoE layers
-    ("zamba2-2.7b", {"n_layers": 4, "window": 8}),  # 2 attention groups
-    ("whisper-base", {})], ids=["dense", "moe", "hybrid-ring", "audio"])
+    ("zamba2-2.7b", {"n_layers": 4}),  # 2 invocations
+    ("zamba2-7b", {"n_layers": 4}),  # the same, heads of 32 cached in 128
+    ("whisper-base", {})],
+    ids=["dense", "moe", "hybrid", "hybrid-wide-heads", "audio"])
 def test_decode_writes_cache_in_place(arch, changes):
     """The layer scan that carries the stacked caches returns the logits and
     caches of the scan over per-layer slices, step after step, with at least
-    two layers in every stack; the hybrid's ring buffer of 8 slots wraps
-    past its window."""
+    two layers in every stack."""
     from repro.serving.engine import ServeEngine
 
     cfg = dataclasses.replace(tiny_variant(get_config(arch)), **changes)
@@ -206,7 +221,7 @@ def test_decode_writes_cache_in_place(arch, changes):
     _, cache = prefill(params, cfg, RUN, prompt,
                        frontend=batch.get("frontend"))
     engine = ServeEngine(cfg, params, run=RUN, batch_size=B)
-    cache = engine._grow_cache(cache, prompt.shape[1] + 12, B)
+    cache = engine._grow_cache(cache, prompt.shape[1] + 12)
     new = jax.jit(lambda c, t: decode_step(params, cfg, RUN, c, t))
     old = jax.jit(lambda c, t: _xs_decode_step(params, cfg, RUN, c, t))
     tok = prompt[:, -1:]
@@ -220,6 +235,64 @@ def test_decode_writes_cache_in_place(arch, changes):
             np.testing.assert_array_equal(np.asarray(got[key]),
                                           np.asarray(want[key]), err_msg=key)
         cache, tok = got, jnp.argmax(got_logits, -1).astype(jnp.int32)
+
+
+@pytest.mark.parametrize("arch", ["tinyllama-1.1b", "zamba2-7b"])
+def test_prefill_in_row_blocks_matches_prefill_at_once(arch):
+    """``RunConfig.prefill_rows``: a wave prefilled a block of rows at a
+    time, in one program, gives the logits and the caches of the whole
+    wave prefilled at once (float32)."""
+    cfg = dataclasses.replace(tiny_variant(get_config(arch)), dtype="float32")
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (6, 16), 0, cfg.vocab)
+    run = dataclasses.replace(RUN, remat="none")
+    whole = prefill(params, cfg, run, tokens)
+    blocks = jax.jit(prefill, static_argnums=(1, 2))(
+        params, cfg, dataclasses.replace(run, prefill_rows=2), tokens)
+    assert jax.tree.structure(blocks) == jax.tree.structure(whole)
+    for got, want in zip(jax.tree.leaves(blocks), jax.tree.leaves(whole)):
+        assert got.shape == want.shape and got.dtype == want.dtype
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=0, atol=1e-5)
+
+
+def test_wide_cache_heads_serve_what_narrow_heads_serve():
+    """A KV cache that stores each head in more lanes than d_head, zeros
+    past it (``kv_head_align``), serves the tokens of a cache of d_head
+    lanes, and their logits within float32 rounding."""
+    from repro.serving import ServeEngine
+
+    base = dataclasses.replace(tiny_variant(get_config("zamba2-7b")),
+                               dtype="float32")
+    params = init_params(base, jax.random.PRNGKey(0))
+    prompts = np.asarray(make_batch(base, jax.random.PRNGKey(1))["tokens"]
+                         [:, :9]).tolist()
+    served = {}
+    for align in (1, 128):
+        cfg = dataclasses.replace(base, kv_head_align=align)
+        assert cfg.cache_head_dim == (32 if align == 1 else 128)
+        served[align] = ServeEngine(cfg, params, run=RUN, batch_size=B
+                                    ).generate(prompts, max_new_tokens=6,
+                                               return_logits=True)
+    assert [r.tokens for r in served[128]] == [r.tokens for r in served[1]]
+    np.testing.assert_allclose(np.stack([r.logits for r in served[128]]),
+                               np.stack([r.logits for r in served[1]]),
+                               rtol=0, atol=1e-5)
+
+
+def test_serve_decode_is_donated_its_cache():
+    """``ServeEngine.decode`` writes the cache it is given in place: the
+    cache it was given is gone after the step."""
+    from repro.serving import ServeEngine
+
+    cfg = tiny_variant(get_config("zamba2-7b"))
+    engine = ServeEngine(cfg, init_params(cfg, jax.random.PRNGKey(0)),
+                         run=RUN, batch_size=B)
+    logits, cache = engine.prefill_wave([[1] * 8, [2] * 8], max_new_tokens=4)
+    tok = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)[:, None]
+    _, grown = engine.decode(engine.params, cache, tok)
+    assert all(cache[key].is_deleted() for key in ("k", "v", "ssm", "conv"))
+    assert int(grown["pos"]) == 9 and grown["k"].shape[2] == 12
 
 
 def test_serve_engine_mixed_prompt_lengths_match_forward():

@@ -52,7 +52,7 @@ def test_jitted_prefill_serves_the_eager_prefills_tokens(engine):
     served = [r.tokens for r in engine.generate(prompts, max_new_tokens=n)]
     logits, cache = prefill(engine.params, engine.cfg, engine.run,
                             jnp.asarray(prompts, jnp.int32))  # eager
-    cache = engine._grow_cache(cache, 11 + n, 2)
+    cache = engine._grow_cache(cache, 11 + n)
     picked = []
     for k in range(n):
         cur = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)

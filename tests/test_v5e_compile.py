@@ -113,7 +113,7 @@ def test_decode_step_bracket_on_v5e(one_chip):
     ("deepseek-moe-16b", 2, 1280),  # moe, a dense layer first, 16 KV heads
     ("phi3.5-moe-42b-a6.6b", 2, 1280),  # moe, 8 KV heads
     ("whisper-base", 2, 448),  # audio self-attention, 8 KV heads of 64
-    ("zamba2-2.7b", 12, 4100),  # hybrid: 2 groups, a ring of 4096 x 32 x 80
+    ("zamba2-2.7b", 13, 4100),  # hybrid: 2 calls, 32 KV heads of 160
 ])
 def test_decode_step_kernel_per_family_on_v5e(one_chip, name, n_layers,
                                               cache_len):
@@ -218,6 +218,74 @@ def test_stacked_decode_in_place_on_v5e(one_chip):
              if op in ("copy", "copy-start")
              and any(squeezed(d) == squeezed(stack) for d in dims)]
     assert len(whole) <= 2, whole
+
+
+def test_hybrid_decode_in_place_on_v5e(one_chip):
+    """Zamba2-7B's decode at the serve cell's widths (12 layers: two
+    invocations, blocks 0 and 1; batch 8, cache 1280) compiles with the
+    flash-decode kernel reading the stacked KV cache once per invocation
+    (32 KV heads of 224 lanes), and each run of Mamba-2 layers a loop that
+    updates the stacked SSM and convolution states in place: nothing in a
+    loop makes a tensor of a whole stack besides those one-layer updates.
+    The engine's decode is donated its cache, and the stack keeps its heads
+    in 256 lanes (``kv_head_align``), which the runtime lays out as the
+    kernel reads them (224-lane heads it lays out positions-minor): no
+    whole copy of K or V."""
+    from repro.kernels.decode_attention import NAME
+    from repro.models import init_cache
+    from repro.models.transformer import hybrid_runs
+    from repro.serving import ServeEngine
+    from repro.train.state import abstract_train_state
+
+    cfg = dataclasses.replace(get_config("zamba2-7b"), n_layers=12)
+    assert len(cfg.hybrid_ids) == 2
+    run = RunConfig(attention_impl="chunked", attention_chunk=512,
+                    remat="none", zero=False)
+    b, t = 8, 1280
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: _sds(one_chip, x.shape, x.dtype), tree)
+
+    params = on_chip(abstract_train_state(cfg).params)
+    cache = on_chip(jax.eval_shape(lambda: init_cache(cfg, b, t)))
+    assert cache["k"].shape == (2, b, t, 32, 256)
+    decode = ServeEngine(cfg, params, run=run, batch_size=b).decode
+    text = decode.lower(params, cache, _sds(one_chip, (b, 1), jnp.int32)
+                        ).compile().as_text()
+    comps = _computations(text)
+    instrs = [i + (line,) for lines in comps.values() for line in lines
+              if (i := _instr(line))]
+    assert sum(NAME in name and op == "custom-call"
+               for name, op, _, _ in instrs) == len(cfg.hybrid_ids)
+
+    def squeezed(dims):
+        return tuple(sorted(d for d in dims if d != 1))
+
+    stacks = {squeezed(cache[k].shape) for k in ("k", "ssm", "conv")}
+
+    def in_place_update(op, line):
+        if op == "dynamic-update-slice":
+            return True
+        called = re.search(r"calls=%?([\w.\-]+)", line)
+        return op == "fusion" and called is not None and any(
+            l.startswith("ROOT") and " dynamic-update-slice(" in l
+            for l in comps[called.group(1)])
+
+    loops = [re.search(r"body=%?([\w.\-]+)", l).group(1)
+             for lines in comps.values() for l in lines if " while(" in l]
+    runs = hybrid_runs(cfg)  # a loop a run of more than one layer
+    assert len(loops) == sum(end - first > 1 for _, first, end in runs)
+    made = [name for loop in loops for line in comps[loop]
+            if (i := _instr(line))
+            for name, op, dims in [i]
+            if op not in ("parameter", "get-tuple-element", "tuple", "bitcast")
+            and not in_place_update(op, line)
+            and any(squeezed(d) in stacks for d in dims)]
+    assert made == [], made
+    whole = [name for name, op, dims, _ in instrs
+             if op in ("copy", "copy-start")
+             and any(squeezed(d) == squeezed(cache["k"].shape) for d in dims)]
+    assert whole == [], whole
 
 
 HBM = 15.75 * 2**30  # one v5e chip, as its compiler counts it
